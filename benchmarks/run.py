@@ -666,17 +666,24 @@ print("SHARDED_JSON " + json.dumps({
 """
 
 
+def _cpu_child_env():
+    """Environment of the forked 4-device benches. They are CPU correctness
+    gates by design, pinned to the CPU so that a parent holding a chip never
+    collides with a child reaching for it."""
+    src = pathlib.Path(__file__).resolve().parent.parent / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = f"{src}:{env.get('PYTHONPATH', '')}".rstrip(":")
+    return env
+
+
 def _bench_sharded_serve():
     """Fork the sharded-vs-single-host pair onto a 4-device CPU mesh (the
     forced device count must be set before jax imports, so this can't run
     in the harness process)."""
     import subprocess
     import sys
-    src = pathlib.Path(__file__).resolve().parent.parent / "src"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{src}:{env.get('PYTHONPATH', '')}".rstrip(":")
-    r = subprocess.run([sys.executable, "-c", _SHARDED_BENCH], env=env,
-                       capture_output=True, text=True)
+    r = subprocess.run([sys.executable, "-c", _SHARDED_BENCH],
+                       env=_cpu_child_env(), capture_output=True, text=True)
     if r.returncode != 0:
         raise RuntimeError(f"sharded serve bench failed:\n{r.stderr[-3000:]}")
     line = [l for l in r.stdout.splitlines()
@@ -843,11 +850,8 @@ def _bench_migration_serve():
     post-rebalance imbalance is fixed tick math."""
     import subprocess
     import sys
-    src = pathlib.Path(__file__).resolve().parent.parent / "src"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{src}:{env.get('PYTHONPATH', '')}".rstrip(":")
-    r = subprocess.run([sys.executable, "-c", _MIGRATION_BENCH], env=env,
-                       capture_output=True, text=True)
+    r = subprocess.run([sys.executable, "-c", _MIGRATION_BENCH],
+                       env=_cpu_child_env(), capture_output=True, text=True)
     if r.returncode != 0:
         raise RuntimeError(f"migration serve bench failed:\n{r.stderr[-3000:]}")
     line = [l for l in r.stdout.splitlines()
@@ -863,11 +867,8 @@ def _bench_chaos_serve():
     recovery counts are pinned integers."""
     import subprocess
     import sys
-    src = pathlib.Path(__file__).resolve().parent.parent / "src"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{src}:{env.get('PYTHONPATH', '')}".rstrip(":")
-    r = subprocess.run([sys.executable, "-c", _CHAOS_BENCH], env=env,
-                       capture_output=True, text=True)
+    r = subprocess.run([sys.executable, "-c", _CHAOS_BENCH],
+                       env=_cpu_child_env(), capture_output=True, text=True)
     if r.returncode != 0:
         raise RuntimeError(f"chaos serve bench failed:\n{r.stderr[-3000:]}")
     line = [l for l in r.stdout.splitlines()
@@ -920,12 +921,8 @@ def bench_kernels():
 # ------------------------------------------------------------------- roofline
 def bench_roofline():
     print("\n## Roofline (from dry-run artifacts, single-pod 256 chips)")
-    try:
-        from repro.launch.roofline import build_table
-        table = build_table()
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline,unavailable,{e}")
-        return
+    from repro.launch.roofline import build_table
+    table = build_table()
     ok = 0
     for key, row in table.items():
         if row["status"] != "ok":
@@ -1009,6 +1006,8 @@ def main():
                          "to a scratch dir and gates them against the "
                          "committed ones via benchmarks.compare)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(SECTIONS)
     t0 = time.time()
     for n in names:

@@ -249,7 +249,7 @@ def _check_attn_core(mod: Module) -> Iterator[Tuple[ast.AST, str]]:
 _SEEDED_NP_RANDOM = {"default_rng", "Generator", "SeedSequence"}
 
 
-def _check_replay_determinism(mod: Module) -> Iterator[Tuple[ast.AST, str]]:
+def _check_deterministic_replay(mod: Module) -> Iterator[Tuple[ast.AST, str]]:
     why = ("fault/sampling/migration paths replay bit-for-bit from a seed — "
            "a wall clock or ambient RNG breaks `chaos_token_divergence == 0`")
     for node in ast.walk(mod.tree):
@@ -571,7 +571,7 @@ RULES: Tuple[Rule, ...] = (
             "src/repro/serve/migration.py",
             "src/repro/serve/scheduler.py",
         ),
-        check=_check_replay_determinism,
+        check=_check_deterministic_replay,
         allow=(),
     ),
     Rule(

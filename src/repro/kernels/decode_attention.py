@@ -6,13 +6,17 @@ cache through VMEM in (block_k × D) tiles with FlashAttention-style online
 softmax, so the cache is read once from HBM and never materialized, copied, or
 cast wholesale (the failure mode the pure-jnp path risks on long contexts).
 
-GQA is native: the query tile is the (G, D) group of query heads that shares
-one KV head, so both matmuls per tile are (G×D)·(D×block_k) and
-(G×block_k)·(block_k×D) — MXU work proportional to real heads only.
+GQA is native: each grid step holds one (block_k, KV, D) tile of the cache
+— every KV head of block_k positions — and loops over the heads; a head's
+query tile is its (G, D) group, so both matmuls per head are (G×D)·(D×block_k)
+and (G×block_k)·(block_k×D) — MXU work proportional to real heads only. The
+tile spans all KV heads because the TPU compiler requires a block's last two
+dims to be (8, 128)-divisible or whole: a one-head (…, 1, D) block of a
+KV = 5 cache does not compile.
 
-Grid: (B, KV, n_k) — batch and kv-head are embarrassingly parallel; the
-k-block sweep is innermost ('arbitrary') so the m/l/acc running statistics
-live in VMEM scratch across it. `kv_len` rides scalar prefetch (SMEM), which
+Grid: (B, n_k) — the batch is embarrassingly parallel; the k-block sweep is
+innermost ('arbitrary') so the per-head m/l/acc running statistics live in
+VMEM scratch across it. `kv_len` rides scalar prefetch (SMEM), which
 lets `pl.when` skip tiles that lie entirely beyond the valid prefix (or, with
 a sliding window, before it): a 4k-deep cache at kv_len=300 runs 3 tiles, not
 32.
@@ -29,7 +33,7 @@ serving engine points unmapped table entries at a reserved null page so the
 prefetch DMA always has a valid source.
 
 INT8 mode (`k_scale=`/`v_scale=`): the caches/pools store int8 rows and the
-scales hold one f16 factor per (position, kv head) — cache shape minus D.
+scales hold one bf16 factor per (position, kv head) — cache shape minus D.
 The scale tiles ride as VMEM operands right next to their K/V tiles (same
 index_map, so the paged gather walks the page table once for both), and
 dequant `int8 → f32 × scale` is fused into the tile load feeding the MXU —
@@ -46,8 +50,9 @@ ONE (kv_lora_rank + qk_rope_dim)-wide pool as both K and V with values the
 leading kv_lora_rank columns of each row (`v_dim=` in
 models/attention.decode_attention). A kernel-side latent gather would load
 each row once and slice V in-register; until then `v_dim` forces the exact
-jnp reference path, which is the CPU oracle anyway. fp8 (e5m2) caches
-likewise stay on the jnp path (dense layout only — see serve/engine).
+jnp reference path, which is the CPU oracle anyway. fp8 (e5m2) caches have
+no kernel: they run the jnp path off-TPU and are refused on TPU
+(models/attention._kernel_dispatch).
 """
 
 from __future__ import annotations
@@ -59,23 +64,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import NEG_INF, CompilerParams
+NEG_INF = -1e30
 
 
-def paged_index_maps(bpp: int, *, n_prefetch: int, g: int = 1):
+def paged_index_maps(bpp: int, *, n_prefetch: int):
     """(kv_map, s_map) BlockSpec index_map factories for page-pool gathers.
 
     Shared by the decode kernel below and the chunk-prefill kernel in
     kernels/flash_attention: both stream K/V (and int8 scale) tiles out of a
-    (n_pages, page_size, ...) pool through a scalar-prefetched page table.
+    (n_pages, page_size, KV, D) pool through a scalar-prefetched page table.
+    A tile spans ALL KV heads — (1, block_k, KV, D) and (1, block_k, KV) —
+    so its last two block dims equal the pool's and satisfy the TPU tiling
+    rule for any head count (a one-head (…, 1, D) block of a KV = 5 pool is
+    refused by the compiler); the kernel bodies loop over heads.
 
-    Grid convention: (batch, head, [q-block,] k-block) with the K-BLOCK INDEX
+    Grid convention: (batch, [q-block,] k-block) with the K-BLOCK INDEX
     LAST among grid dims and the PAGE TABLE LAST among the `n_prefetch`
-    scalar-prefetch refs. `bpp` is k-blocks per page; `g` divides a flattened
-    query-head grid index down to its KV head (1 when the grid already runs
-    over KV heads, as in the decode kernel).
+    scalar-prefetch refs. `bpp` is k-blocks per page.
 
-    Device-locality contract (sharded serving, PR 5): the table values these
+    Device-locality contract (sharded serving): the table values these
     maps read become DMA source pages, so every entry must address the pool
     operand THIS kernel instance was handed. Under the sharded engine the
     global pool is partitioned page-wise across the mesh's data axis and the
@@ -86,13 +93,13 @@ def paged_index_maps(bpp: int, *, n_prefetch: int, g: int = 1):
     keep tables device-local (serve/scheduler reserves pages per shard and
     ShardedServeEngine.assert_local_page_tables pins the invariant)."""
 
-    def kv_map(ib, ih, *rest):
+    def kv_map(ib, *rest):
         ik, pt_ref = rest[len(rest) - n_prefetch - 1], rest[-1]
-        return pt_ref[ib, ik // bpp], ik % bpp, ih // g, 0
+        return pt_ref[ib, ik // bpp], ik % bpp, 0, 0
 
-    def s_map(ib, ih, *rest):
+    def s_map(ib, *rest):
         ik, pt_ref = rest[len(rest) - n_prefetch - 1], rest[-1]
-        return pt_ref[ib, ik // bpp], ik % bpp, ih // g
+        return pt_ref[ib, ik // bpp], ik % bpp, 0
 
     return kv_map, s_map
 
@@ -102,10 +109,13 @@ def _body(kvlen_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
           n_k: int):
     """Online-softmax tile update, shared by all four (paged × int8) kernel
     layouts — position-based, so it is blind to where the tile bytes came
-    from and whether they were dequantized on the way in."""
+    from and whether they were dequantized on the way in. One grid step
+    holds every KV head's (block_k, D) tile and updates each head's (G, ·)
+    running statistics in turn."""
     b = pl.program_id(0)
-    ik = pl.program_id(2)
+    ik = pl.program_id(1)
     kvlen = kvlen_ref[b]
+    nkv, g = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(ik == 0)
     def _init():
@@ -121,35 +131,38 @@ def _body(kvlen_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
 
     @pl.when(live)
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (block_k, D)
-        if ks_ref is not None:                          # fused dequant
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, block_k)
         k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, (g, block_k), 1)
         ok = k_pos < kvlen
         if window > 0:
             ok = jnp.logical_and(ok, k_pos >= kvlen - window)
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # (block_k, D)
-        if vs_ref is not None:
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+        if ks_ref is not None:                          # fused dequant
+            k_sc = ks_ref[0].astype(jnp.float32)        # (block_k, KV)
+            v_sc = vs_ref[0].astype(jnp.float32)
+        for h in range(nkv):
+            q = q_ref[0, h].astype(jnp.float32)         # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)   # (block_k, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if ks_ref is not None:
+                k = k * k_sc[:, h:h + 1]
+                v = v * v_sc[:, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, block_k)
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_k - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _make_kernel(*, paged: bool, quantized: bool, **kw):
@@ -208,80 +221,58 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, page_table=None,
         jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
     qf = q.reshape(b, nkv, g, d)
     scratch_shapes = [
-        pltpu.VMEM((g, 1), jnp.float32),   # m
-        pltpu.VMEM((g, 1), jnp.float32),   # l
-        pltpu.VMEM((g, d), jnp.float32),   # acc
+        pltpu.VMEM((nkv, g, 1), jnp.float32),   # m
+        pltpu.VMEM((nkv, g, 1), jnp.float32),   # l
+        pltpu.VMEM((nkv, g, d), jnp.float32),   # acc
     ]
-    q_spec = pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik, *_: (ib, ih, 0, 0))
-    out_spec = pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik, *_: (ib, ih, 0, 0))
+    q_spec = pl.BlockSpec((1, nkv, g, d), lambda ib, ik, *_: (ib, 0, 0, 0))
 
     if page_table is None:
         smax = k_cache.shape[1]
         block_k = min(block_k, smax)
-        assert smax % block_k == 0, (smax, block_k)
+        if smax % block_k:
+            block_k = smax      # a whole-depth block is always tile-legal
         n_k = smax // block_k
-        kv_spec = pl.BlockSpec((1, block_k, 1, d),
-                               lambda ib, ih, ik, *_: (ib, ik, ih, 0))
-        in_specs = [q_spec, kv_spec, kv_spec]
-        operands = [qf, k_cache, v_cache]
-        if quantized:
-            s_spec = pl.BlockSpec((1, block_k, 1),
-                                  lambda ib, ih, ik, *_: (ib, ik, ih))
-            in_specs += [s_spec, s_spec]
-            operands += [k_scale, v_scale]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, nkv, n_k),
-            in_specs=in_specs,
-            out_specs=out_spec,
-            scratch_shapes=scratch_shapes,
-        )
-        out = pl.pallas_call(
-            _make_kernel(paged=False, quantized=quantized, scale=scale,
-                         window=window, block_k=block_k, n_k=n_k),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
-            interpret=interpret,
-            compiler_params=CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-        )(kv_len, qf, *operands[1:])
-        return out.reshape(b, 1, nkv, g, d)
+        n_prefetch = 1
+        kv_map = lambda ib, ik, *_: (ib, ik, 0, 0)      # noqa: E731
+        s_map = lambda ib, ik, *_: (ib, ik, 0)          # noqa: E731
+        prefetch = [kv_len]
+    else:
+        page_size = k_cache.shape[1]
+        pages_per_seq = page_table.shape[1]
+        assert page_table.shape[0] == b, (page_table.shape, b)
+        block_k = min(block_k, page_size)
+        assert page_size % block_k == 0, (page_size, block_k)
+        bpp = page_size // block_k              # k-blocks per page
+        n_k = pages_per_seq * bpp               # logical k-block sweep
+        n_prefetch = 2
+        # physical page of each tile's logical page via prefetch; the scale
+        # tile gathers through the same table entry as its K/V tile
+        kv_map, s_map = paged_index_maps(bpp, n_prefetch=2)
+        prefetch = [kv_len, jnp.asarray(page_table, jnp.int32)]
 
-    # ------------------------------------------------------------- paged path
-    page_size = k_cache.shape[1]
-    pages_per_seq = page_table.shape[1]
-    assert page_table.shape[0] == b, (page_table.shape, b)
-    block_k = min(block_k, page_size)
-    assert page_size % block_k == 0, (page_size, block_k)
-    bpp = page_size // block_k              # k-blocks per page
-    n_k = pages_per_seq * bpp               # logical k-block sweep
-    page_table = jnp.asarray(page_table, jnp.int32)
-
-    # physical page of each tile's logical page via prefetch; the scale tile
-    # gathers through the same table entry as its K/V tile
-    kv_map, s_map = paged_index_maps(bpp, n_prefetch=2)
-
-    kv_spec = pl.BlockSpec((1, block_k, 1, d), kv_map)
+    kv_spec = pl.BlockSpec((1, block_k, nkv, d), kv_map)
     in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [k_cache, v_cache]
+    operands = [qf, k_cache, v_cache]
     if quantized:
-        s_spec = pl.BlockSpec((1, block_k, 1), s_map)
+        s_spec = pl.BlockSpec((1, block_k, nkv), s_map)
         in_specs += [s_spec, s_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nkv, n_k),
+        num_scalar_prefetch=n_prefetch,
+        grid=(b, n_k),
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=q_spec,
         scratch_shapes=scratch_shapes,
     )
     out = pl.pallas_call(
-        _make_kernel(paged=True, quantized=quantized, scale=scale,
-                     window=window, block_k=block_k, n_k=n_k),
+        _make_kernel(paged=page_table is not None, quantized=quantized,
+                     scale=scale, window=window, block_k=block_k, n_k=n_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
+        name="decode_attention",
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(kv_len, page_table, qf, *operands)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(*prefetch, *operands)
     return out.reshape(b, 1, nkv, g, d)

@@ -35,8 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import NEG_INF, CompilerParams
-from repro.kernels.decode_attention import paged_index_maps
+from repro.kernels.decode_attention import NEG_INF, paged_index_maps
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -125,7 +124,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, d), jnp.float32),   # acc
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)
@@ -143,14 +142,19 @@ def _paged_kernel(off_ref, kvlen_ref, pt_ref, *refs, scale: float,
     Ref order after the scalar prefetch (q_offset, kv_len, page_table):
     inputs (q, k, v[, ks, vs]), output (o), scratch (m, l, acc). The page
     table is consumed by the K/V index_maps — the body only sees positions.
+    The K/V tile holds every KV head (see paged_index_maps); the body loops
+    over heads, and a head's query tile is its G query heads × block_q rows
+    stacked into one (G·block_q, D) operand, row r at chunk row r % block_q.
     """
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
     ks_ref, vs_ref = (refs[3], refs[4]) if quantized else (None, None)
     o_ref, m_ref, l_ref, acc_ref = refs[-4], refs[-3], refs[-2], refs[-1]
+    nkv, g, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[4]
+    rows = g * block_q
     ib = pl.program_id(0)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
     kvlen = kvlen_ref[ib]
     q_lo = off_ref[ib] + iq * block_q          # global position of q row 0
 
@@ -170,39 +174,44 @@ def _paged_kernel(off_ref, kvlen_ref, pt_ref, *refs, scale: float,
 
     @pl.when(live)
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32)             # (block_q, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (block_k, D)
-        if ks_ref is not None:                          # fused int8 dequant
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
         q_pos = q_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+            jnp.int32, (rows, block_k), 0) % block_q
         k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, (rows, block_k), 1)
         ok = jnp.logical_and(k_pos <= q_pos, k_pos < kvlen)
         if window > 0:
             ok = jnp.logical_and(ok, q_pos - k_pos < window)
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # rows with no valid key yet keep m == NEG_INF; NEG_INF is finite, so
-        # exp(s - m) would be exp(0)=1 for their masked entries — zero them
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if vs_ref is not None:
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+        if ks_ref is not None:                          # fused int8 dequant
+            k_sc = ks_ref[0].astype(jnp.float32)        # (block_k, KV)
+            v_sc = vs_ref[0].astype(jnp.float32)
+        for h in range(nkv):
+            q = q_ref[0, h].astype(jnp.float32).reshape(rows, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32)   # (block_k, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if ks_ref is not None:
+                k = k * k_sc[:, h:h + 1]
+                v = v * v_sc[:, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # rows with no valid key yet keep m == NEG_INF; NEG_INF is
+            # finite, so exp(s - m) would be exp(0)=1 for their masked
+            # entries — zero them
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_k - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        for h in range(nkv):
+            o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = o.reshape(g, block_q, d).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -239,33 +248,31 @@ def flash_attention_paged(q, k_pool, v_pool, page_table, q_offset, kv_len, *,
     n_k = pages_per_seq * bpp
     block_q = min(block_q, cq)
     assert cq % block_q == 0, (cq, block_q)
-    h = nkv * g
     q_offset = jnp.asarray(q_offset, jnp.int32).reshape(b)
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
     page_table = jnp.asarray(page_table, jnp.int32)
-    qf = jnp.moveaxis(q.reshape(b, cq, h, d), 1, 2)    # (B, H, C, D)
+    qf = jnp.transpose(q, (0, 2, 3, 1, 4))             # (B, KV, G, C, D)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda ib, ih, iq, ik, *_: (ib, ih, iq, 0))
-    out_spec = pl.BlockSpec((1, 1, block_q, d),
-                            lambda ib, ih, iq, ik, *_: (ib, ih, iq, 0))
-    kv_map, s_map = paged_index_maps(bpp, n_prefetch=3, g=g)
-    kv_spec = pl.BlockSpec((1, block_k, 1, d), kv_map)
+    q_spec = pl.BlockSpec((1, nkv, g, block_q, d),
+                          lambda ib, iq, ik, *_: (ib, 0, 0, iq, 0))
+    kv_map, s_map = paged_index_maps(bpp, n_prefetch=3)
+    kv_spec = pl.BlockSpec((1, block_k, nkv, d), kv_map)
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qf, k_pool, v_pool]
     if quantized:
-        s_spec = pl.BlockSpec((1, block_k, 1), s_map)
+        s_spec = pl.BlockSpec((1, block_k, nkv), s_map)
         in_specs += [s_spec, s_spec]
         operands += [k_scale, v_scale]
+    rows = g * block_q
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, h, cq // block_q, n_k),
+        grid=(b, cq // block_q, n_k),
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # m
-            pltpu.VMEM((block_q, 1), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32),   # acc
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),   # m
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),   # l
+            pltpu.VMEM((nkv, rows, d), jnp.float32),   # acc
         ],
     )
     out = pl.pallas_call(
@@ -273,10 +280,10 @@ def flash_attention_paged(q, k_pool, v_pool, page_table, q_offset, kv_len, *,
                           block_q=block_q, block_k=block_k, n_k=n_k,
                           quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, cq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        name="flash_attention_paged",
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q_offset, kv_len, page_table, *operands)
-    return jnp.moveaxis(out, 1, 2).reshape(b, cq, nkv, g, d)
+    return jnp.transpose(out, (0, 3, 1, 2, 4))         # (B, C, KV, G, D)

@@ -8,16 +8,11 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types on make_mesh
-    from jax.sharding import AxisType
 
-    def _mk(shape, axes):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # older jax: every axis is Auto already
-    def _mk(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mk(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -1,7 +1,10 @@
 """Serving driver: continuous-batching engine over a chosen architecture.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
       --requests 16 --slots 4 [--wdtype int8] [--kv-dtype int8]
+
+`--smoke` (the default) serves the reduced CPU config; `--no-smoke` serves
+the architecture at its published widths.
 
 `--wdtype int8 --kv-dtype int8` is the paper's "AI-optimized" serving
 numerics: weight-only int8 projections (Pallas int8_matmul on TPU) plus an
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ExecOptions, build_model
 from repro.serve.engine import ServeEngine
 
@@ -54,7 +58,10 @@ def quantize_params_int8(params):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced same-family config (--no-smoke: published "
+                         "widths)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=96)
@@ -125,6 +132,7 @@ def main():
                     help="nucleus top-p filter (1.0 = off)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

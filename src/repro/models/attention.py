@@ -124,25 +124,21 @@ def chunked_attention(q, k, v, *, causal=True, window=0, scale=None,
     return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
 
-def _pallas_decode_ok(q, k_cache, page_table=None) -> bool:
-    """The Pallas decode kernel needs a TPU backend and a cache depth that
-    tiles evenly; everything else falls back to the pure-jnp path."""
+def _kernel_dispatch(k_cache) -> bool:
+    """impl='auto' dispatch of the paged/dense attention kernels: on TPU the
+    Pallas kernel always runs — a cache it cannot tile fails loudly in the
+    kernel's own shape checks instead of quietly taking the jnp path, which
+    would densify the whole logical view per step. Every other backend runs
+    the jnp reference (the CPU oracle; tests force the kernel in interpret
+    mode with impl='pallas')."""
     if jax.default_backend() != "tpu":
         return False
-    if jnp.issubdtype(k_cache.dtype, jnp.floating) and k_cache.dtype.itemsize == 1:
-        return False  # fp8 caches: jnp path only (dense layout, CPU tests)
-    # int8 pools tile at 32 sublanes (vs 16 for bf16): require 32-row pages
-    sublane = 32 if k_cache.dtype == jnp.int8 else 16
-    if page_table is not None:
-        # auto-dispatch whenever a page is sublane-tileable for the storage
-        # dtype; the serving default (32) qualifies for both — falling back
-        # to the jnp path would densify the whole logical view per step,
-        # re-buying the dense cache the pool exists to avoid. Smaller pages
-        # (tests) still run via impl='pallas'.
-        page_size = k_cache.shape[1]
-        return page_size >= sublane and page_size % sublane == 0
-    smax = k_cache.shape[1]
-    return smax % min(128, smax) == 0 and smax >= 128
+    if jnp.issubdtype(k_cache.dtype, jnp.floating) \
+            and k_cache.dtype.itemsize == 1:
+        raise NotImplementedError(
+            "fp8 (e5m2) KV caches have no TPU attention kernel; serve "
+            "bf16 or int8 KV on TPU")
+    return True
 
 
 def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
@@ -168,7 +164,7 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
     through scalar prefetch and never materializes the dense view.
 
     INT8 caches (`k_scale`/`v_scale`): the caches hold int8 rows and the
-    scales hold one f16 dequant factor per (position, kv head) — shaped like
+    scales hold one bf16 dequant factor per (position, kv head) — shaped like
     the caches minus the D dim. Dequant is `int8.astype(f32) * scale` — the
     jnp path materializes it on the gathered view (CPU oracle), the Pallas
     kernel fuses it into the K/V tile loads so the cache crosses HBM as int8.
@@ -187,8 +183,8 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
     assert (k_scale is None) == (v_scale is None)
     if v_dim is not None:
         impl = "reference"  # latent-row kernel gather is a follow-on
-    if impl == "auto" and _pallas_decode_ok(q, k_cache, page_table):
-        impl = "pallas"
+    if impl == "auto":
+        impl = "pallas" if _kernel_dispatch(k_cache) else "reference"
     if impl == "pallas":
         from repro.kernels.decode_attention import (
             decode_attention as pallas_decode)
@@ -236,20 +232,6 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
     return o.astype(q.dtype)
 
 
-def _pallas_chunk_ok(q, k_pool) -> bool:
-    """Chunk-prefill kernel dispatch: TPU + sublane-tileable pages (32 rows
-    for int8 pools, 16 for bf16) + a chunk the q-block tiles evenly."""
-    if jax.default_backend() != "tpu":
-        return False
-    if jnp.issubdtype(k_pool.dtype, jnp.floating) and k_pool.dtype.itemsize == 1:
-        return False  # fp8 pools: jnp path only
-    sublane = 32 if k_pool.dtype == jnp.int8 else 16
-    page_size = k_pool.shape[1]
-    cq = q.shape[1]
-    return (page_size >= sublane and page_size % sublane == 0
-            and cq % min(128, cq) == 0)
-
-
 def chunk_attention_paged(q, k_pool, v_pool, page_table, q_offset, *, kv_len,
                           window=0, scale=None, k_scale=None, v_scale=None,
                           v_dim: Optional[int] = None, impl: str = "auto"):
@@ -281,8 +263,8 @@ def chunk_attention_paged(q, k_pool, v_pool, page_table, q_offset, *, kv_len,
     scale = scale if scale is not None else d ** -0.5
     if v_dim is not None:
         impl = "reference"
-    if impl == "auto" and _pallas_chunk_ok(q, k_pool):
-        impl = "pallas"
+    if impl == "auto":
+        impl = "pallas" if _kernel_dispatch(k_pool) else "reference"
     if impl == "pallas":
         from repro.kernels.flash_attention import flash_attention_paged
         return flash_attention_paged(

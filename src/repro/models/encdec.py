@@ -316,7 +316,7 @@ def cache_shape(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, *,
     contract: transformer.paged_kv_shapes); cross K/V stay dense per slot —
     they are written once at prefill at a fixed (cross_len) depth, so paging
     would buy nothing and cost a second table. dtype=jnp.int8 quantizes the
-    self-attention K/V only (plus 'ks'/'vs' f16 row scales); cross K/V keep
+    self-attention K/V only (plus 'ks'/'vs' bf16 row scales); cross K/V keep
     f32 — written once, read every step, and a second dequant operand per
     layer would buy back ~cross_len/max_len of the savings at best."""
     L, kv, hd, se = cfg.n_dec_layers, cfg.kv_pad, cfg.head_dim, cfg.cross_len

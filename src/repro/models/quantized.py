@@ -21,7 +21,7 @@ hot path onto that datapath:
   * `quantize_kv_rows` is the KV-cache row quantizer shared by the dense and
     paged int8 KV write paths (models/transformer, models/encdec, the serve
     engine's paste programs): per-token-per-head symmetric int8 over the head
-    dim, scale stored in f16 — the scale rides one value per (position, kv
+    dim, scale stored in bf16 — the scale rides one value per (position, kv
     head), so the pool overhead is 2/(2·D) over bf16 and the quantized values
     are identical regardless of cache layout, which is what makes the paged
     int8 engine token-exact against the dense int8 oracle.
@@ -139,20 +139,28 @@ def qeinsum(eq: str, x: jnp.ndarray, w, *, impl: str = "auto",
     dot (XLA fuses the convert — the weight is never materialized in float)
     and applies the per-channel scale to the f32 accumulator, mirroring the
     kernel's epilogue.
+
+    On TPU a kernel refusal (an einsum pattern or a shape the kernel cannot
+    tile) raises instead of quietly running the jnp path, so a quantized
+    projection can never leave the int8 datapath unnoticed. Off-TPU the
+    refusal falls back to the jnp reference — the CPU oracle.
     """
     if not is_quantized(w):
         return jnp.einsum(eq, x, w)
     q, s = w[_QKEY], w["s"]
     xs, ws, out, contract, batch, wout = _parse(eq)
-    use_pallas = impl == "pallas" or (
-        impl == "auto" and jax.default_backend() == "tpu")
-    if use_pallas:
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "pallas" or (impl == "auto" and on_tpu):
         got = _try_pallas(
             x, q, s, xs, ws, out, contract, batch, wout,
-            interpret=interpret if interpret is not None
-            else jax.default_backend() != "tpu")
+            interpret=interpret if interpret is not None else not on_tpu)
         if got is not None:
             return got
+        if on_tpu:
+            raise ValueError(
+                f"int8_matmul cannot run {eq!r} with x {x.shape} and w "
+                f"{q.shape} on TPU (einsum pattern or block fit); refusing "
+                "to fall back to the jnp dequant path")
     acc = jnp.einsum(eq, x, q.astype(x.dtype),
                      preferred_element_type=jnp.float32)
     acc = acc * _scale_for_output(s, ws, out, acc.shape)
@@ -161,7 +169,7 @@ def qeinsum(eq: str, x: jnp.ndarray, w, *, impl: str = "auto",
 
 def _try_pallas(x, q, s, xs, ws, out, contract, batch, wout, *, interpret):
     """Reshape-to-2D dispatch onto kernels/int8_matmul; None when the einsum
-    pattern or the block divisibility doesn't fit (caller falls back to jnp).
+    pattern or the kernel's block contract (`pick_blocks`) doesn't fit.
 
     Handled patterns (every projection call site in models/):
       no batch dim:  xs = <x-out><contract>, ws = <contract><wout>,
@@ -182,7 +190,7 @@ def _try_pallas(x, q, s, xs, ws, out, contract, batch, wout, *, interpret):
     if ws[len(c):] != "".join(wout) or out != x_out + "".join(wout):
         return None
 
-    from repro.kernels.int8_matmul import blocks_fit
+    from repro.kernels.int8_matmul import pick_blocks
 
     def dims(x_shape, q_size):
         m = k = 1
@@ -199,13 +207,13 @@ def _try_pallas(x, q, s, xs, ws, out, contract, batch, wout, *, interpret):
         return out2.reshape(xe.shape[:len(x_out)] + qe.shape[len(c):])
 
     if not b:
-        if not blocks_fit(*dims(x.shape, q.size)):
-            return None     # kernel's clamped blocks don't tile this shape
+        if pick_blocks(*dims(x.shape, q.size), x.dtype.itemsize) is None:
+            return None
         return flat_mm(x, q, s.reshape(q.shape[len(c):]))
     # batched (expert) path: shapes are uniform over the batch dim — check
-    # divisibility on the slice shapes, then vmap the kernel (one leading
-    # grid dim per expert)
-    if not blocks_fit(*dims(x.shape[1:], q[0].size)):
+    # the block contract on the slice shapes, then vmap the kernel (one
+    # leading grid dim per expert)
+    if pick_blocks(*dims(x.shape[1:], q[0].size), x.dtype.itemsize) is None:
         return None
     return jax.vmap(lambda xe, qe, se: flat_mm(
         xe, qe, se.reshape(qe.shape[len(c):])))(x, q, s)
@@ -215,10 +223,12 @@ def _try_pallas(x, q, s, xs, ws, out, contract, batch, wout, *, interpret):
 # INT8 KV-cache row quantization (shared by dense + paged layouts)
 # ---------------------------------------------------------------------------
 
-SCALE_DTYPE = jnp.float16  # absmax/127 of unit-scale activations: range is
-#                            tiny, mantissa (2^-11) is 8x below the int8 grid
-#                            error, and a 2-byte scale keeps the int8 pool at
-#                            (D+2)/(2D) of bf16 even at smoke head dims.
+# bf16, not f16: the TPU kernels cannot load f16 tiles on v5e ("Invalid
+# vector type for load"), while bf16 loads natively. The scale is rounded
+# before the ints are computed against it, so its 2^-9 relative rounding
+# moves the int8 grid, not the reconstruction error (still ~s/2), and a
+# 2-byte scale keeps the int8 pool at (D+2)/(2D) of bf16.
+SCALE_DTYPE = jnp.bfloat16
 
 
 def quantize_kv_rows(kv: jnp.ndarray):
@@ -232,9 +242,9 @@ def quantize_kv_rows(kv: jnp.ndarray):
     """
     kvf = kv.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(kvf), axis=-1)
-    # floor the SCALE (not the absmax) at an f16-representable value: an
-    # all-zero row must quantize to (0, tiny) — a sub-f16 scale would store
-    # as 0.0 and turn the next dequant-divide into NaN
+    # floor the SCALE (not the absmax) at a positive value: an all-zero row
+    # must quantize to (0, tiny) — a zero scale would turn the next
+    # dequant-divide into NaN
     s = jnp.maximum(absmax / 127.0, 1e-6).astype(SCALE_DTYPE)
     sf = s.astype(jnp.float32)
     q = jnp.clip(jnp.round(kvf / sf[..., None]), -127, 127).astype(jnp.int8)

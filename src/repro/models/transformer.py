@@ -275,7 +275,7 @@ def _write_cache(cache, kv_new, positions):
 
 def _write_cache_q(cache, scales, kv_new, positions):
     """Dense int8 KV write: quantize the new (B,1,KV,D) row per (token, kv
-    head) and masked-set both the int8 cache row and its f16 scale. Same
+    head) and masked-set both the int8 cache row and its bf16 scale. Same
     one-hot masking as `_write_cache` but via `where` — int8 arithmetic has
     no exact multiply-by-mask. Returns (cache, scales)."""
     q, s = quantize_kv_rows(kv_new)                 # (B,1,KV,D) i8, (B,1,KV)
@@ -423,7 +423,7 @@ def gather_pool_pages(cache, page_ids):
     pool's page axis device-local, all_gathers the outboxes, and scatters
     with `set_pool_page`. Because the gather snapshots BEFORE any scatter
     runs, a page may be both exported and overwritten in the same move wave.
-    Pool-native bytes move as-is — an int8 pool's int8 rows + f16 scale rows
+    Pool-native bytes move as-is — an int8 pool's int8 rows + bf16 scale rows
     ARE its block-compressed wire format (half the bf16 bytes), and decode's
     fused dequant is the receive-side decompress — so migrated pages are
     bit-exact under the schedule-independent KV rounding contract."""
@@ -747,7 +747,7 @@ def cache_shape(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, *,
     — the per-token bytes the latent family exists to shrink.
     Dense (default): per-slot (L, B, max_len, KV, D) rows.
     Paged (`page_size=`): shared page pools — see `paged_kv_shapes`.
-    dtype=jnp.int8 (either layout): rows stored int8 plus per-row f16 dequant
+    dtype=jnp.int8 (either layout): rows stored int8 plus per-row bf16 dequant
     scale tensors ('ks'/'vs') — the serving engine's kv_dtype='int8' layout.
     dtype=jnp.float8_e5m2: bare fp8 rows, no scale tensors (dense layout;
     the engine keeps paged fp8 pools a follow-on)."""

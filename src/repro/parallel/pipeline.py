@@ -28,8 +28,7 @@ def pipeline_forward(stage_fn: Callable, x_micro: jnp.ndarray,
     identical on every stage (only stage 0's values are consumed).
     Returns (n_micro, mb, ...) outputs valid on the LAST stage.
     """
-    from repro.parallel.shmap import axis_size
-    n_stage = axis_size(axis_name)
+    n_stage = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     n_micro = x_micro.shape[0]
     n_ticks = n_micro + n_stage - 1
@@ -83,8 +82,7 @@ def run_pipeline(mesh, stage_fn: Callable, params_stacked, x: jnp.ndarray,
         return jax.lax.psum(masked, axis_name)
 
     spec_p = jax.tree.map(lambda _: P(axis_name), params_stacked)
-    from repro.parallel.shmap import shard_map
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(spec_p, P()), out_specs=P(),
         check_vma=False))(params_stacked, xm)
     return out.reshape(x.shape[0], *out.shape[2:])

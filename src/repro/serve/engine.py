@@ -18,7 +18,7 @@ measured serving numerics):
     TPU, jnp dequant-matmul reference elsewhere; MoE experts quantized per
     expert). Halves weight HBM traffic per decode step — the bound at small
     batch.
-  * `kv_dtype="int8"`: K/V stored int8 with per-(token, kv head) f16 dequant
+  * `kv_dtype="int8"`: K/V stored int8 with per-(token, kv head) bf16 dequant
     scales ('ks'/'vs' tensors riding next to 'k'/'v' in either cache
     layout). Quantization happens at write time (prefill paste + decode
     write); dequant is fused into the decode-attention kernel's K/V tile
@@ -732,12 +732,6 @@ class ServeEngine:
         self._seen = np.zeros((n_slots, self.cfg.vocab_size), bool)
         self._bias = np.zeros((n_slots, self.cfg.vocab_size), np.float32)
         self._bias_on = np.zeros((n_slots,), bool)
-        # donation is unimplemented on CPU (harmless but warns per compile)
-        donate = {} if jax.default_backend() == "cpu" else \
-            {"donate_argnums": (2,)}
-        paste_donate = {} if jax.default_backend() == "cpu" else \
-            {"donate_argnums": (0,)}
-
         # Replay admissions discard prefill logits — use the cache-only
         # prefill (no LM-head matmul) when the family provides one.
         cache_only = self._replay and model.prefill_cache is not None
@@ -805,14 +799,11 @@ class ServeEngine:
                 return dict(cache, page_table=cache["page_table"]
                             .at[slot, j_dead].set(0))
 
-            self._unmap_jit = jax.jit(_unmap, **paste_donate)
-            self._remap_entry_jit = jax.jit(_remap_entry, **paste_donate)
-            self._unmap_entry_jit = jax.jit(_unmap_entry, **paste_donate)
+            self._unmap_jit = jax.jit(_unmap, donate_argnums=(0,))
+            self._remap_entry_jit = jax.jit(_remap_entry, donate_argnums=(0,))
+            self._unmap_entry_jit = jax.jit(_unmap_entry, donate_argnums=(0,))
 
             if self.chunked:
-                chunk_donate = {} if jax.default_backend() == "cpu" else \
-                    {"donate_argnums": (2,)}
-
                 def _chunk(params, batch, cache):
                     self.stats.chunk_compiles += 1   # trace time only
                     return model.prefill_chunk(params, batch, cache)
@@ -826,11 +817,11 @@ class ServeEngine:
                     c["pos"] = c["pos"].at[slot].set(pos)
                     return c
 
-                self._chunk_jit = jax.jit(_chunk, **chunk_donate)
-                self._finalize_jit = jax.jit(_finalize, **paste_donate)
+                self._chunk_jit = jax.jit(_chunk, donate_argnums=(2,))
+                self._finalize_jit = jax.jit(_finalize, donate_argnums=(0,))
                 # COW tail clone: duplicate one physical page across every
                 # pool (models/transformer.copy_pool_page), cache donated
-                self._cow_jit = jax.jit(copy_pool_page, **paste_donate)
+                self._cow_jit = jax.jit(copy_pool_page, donate_argnums=(0,))
                 if model.prefill_cross is not None:
                     self._cross_jit = jax.jit(model.prefill_cross)
 
@@ -843,16 +834,16 @@ class ServeEngine:
                         return c
 
                     self._paste_cross_jit = jax.jit(_paste_cross,
-                                                    **paste_donate)
+                                                    donate_argnums=(0,))
         else:
             def _paste(cache, pf, slot, pos):
                 self.stats.paste_compiles += 1
                 return _make_paste(self.cfg.family)(cache, pf, slot, pos)
 
         self._prefill_jit = jax.jit(_prefill)
-        self._decode_jit = jax.jit(_decode, **donate)
-        self._decode_sample_jit = jax.jit(_decode_sample, **donate)
-        self._paste_jit = jax.jit(_paste, **paste_donate)
+        self._decode_jit = jax.jit(_decode, donate_argnums=(2,))
+        self._decode_sample_jit = jax.jit(_decode_sample, donate_argnums=(2,))
+        self._paste_jit = jax.jit(_paste, donate_argnums=(0,))
         # non-replay first-token sampler (recurrent families sample their
         # first output from the prefill logits, counter 0)
         self._sample1_jit = jax.jit(sample_tokens)

@@ -22,7 +22,7 @@ Three triggers share the one primitive:
     (guarded by `min_prefix_hits`).
 
 Exactness contract: the data path moves POOL-NATIVE bytes, verbatim. An
-int8 KV pool's int8 rows + f16 scale rows *are* its block-compressed wire
+int8 KV pool's int8 rows + bf16 scale rows *are* its block-compressed wire
 format — exactly half the bf16 bytes, produced by `kernels/quantize`'s
 block quantization at write time and decompressed by decode's fused dequant
 on the receiving shard — so "gather → block-compress → move → decompress →
@@ -69,7 +69,7 @@ class MigrationConfig:
 def page_payload_bytes(pools) -> int:
     """Bytes ONE physical page occupies across every pool array (the page
     axis is axis 1). Pool-native: an int8 pool contributes its int8 rows
-    plus f16 block scales — the block-compressed wire format — so int8
+    plus bf16 row scales — the block-compressed wire format — so int8
     migrations genuinely ship about half the bf16 bytes."""
     return int(sum(x.size // x.shape[1] * x.dtype.itemsize
                    for x in pools.values()))

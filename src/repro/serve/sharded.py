@@ -4,7 +4,7 @@ The paper's scale-out story (§II: dual NPU chiplets behind an AI-aware UCIe
 interconnect) as a serving runtime: `ShardedServeEngine` partitions the
 decode batch's slots AND the paged KV pool across a mesh axis (the
 production mesh's 'data' axis — one shard per chiplet/device) via
-`parallel/shmap.shard_map`, so the whole fleet decodes in ONE jitted global
+`jax.shard_map`, so the whole fleet decodes in ONE jitted global
 step while every byte of KV traffic stays on the device that owns it.
 
 Layout invariants (what makes this GSPMD-proof instead of GSPMD-hostile):
@@ -74,7 +74,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis.sanitizer import register_entry_point
 from repro.models.transformer import gather_pool_pages, set_pool_page
-from repro.parallel.shmap import shard_map
 from repro.serve.engine import (
     _KV_DTYPES, EngineOverloaded, EngineStats, Request)
 from repro.serve.faults import FaultPlan
@@ -267,8 +266,6 @@ class ShardedServeEngine:
         # ---- the jitted global programs ------------------------------------
         vocab = self.cfg.vocab_size
         pspecs = self._param_specs
-        donate = {} if jax.default_backend() == "cpu" else \
-            {"donate_argnums": (2,)}
 
         def _decode_core(params, tokens, pools, pt, pos):
             cache = dict(pools, page_table=pt, pos=pos)
@@ -296,15 +293,17 @@ class ShardedServeEngine:
         sample_specs = {k: vec_spec for k in
                         ("temperature", "top_k", "top_p", "seed", "counter")}
 
-        self._decode_jit = jax.jit(shard_map(
+        self._decode_jit = jax.jit(jax.shard_map(
             _decode_greedy, mesh=mesh,
             in_specs=(pspecs, tok_spec, self._pool_specs, pt_spec, vec_spec),
-            out_specs=(vec_spec, self._pool_specs)), **donate)
-        self._decode_sample_jit = jax.jit(shard_map(
+            out_specs=(vec_spec, self._pool_specs), check_vma=False),
+            donate_argnums=(2,))
+        self._decode_sample_jit = jax.jit(jax.shard_map(
             _decode_sample, mesh=mesh,
             in_specs=(pspecs, tok_spec, self._pool_specs, pt_spec, vec_spec,
                       sample_specs),
-            out_specs=(vec_spec, self._pool_specs)), **donate)
+            out_specs=(vec_spec, self._pool_specs), check_vma=False),
+            donate_argnums=(2,))
 
         def _chunk(params, batch, pools):
             self.stats.chunk_compiles += 1      # trace time only
@@ -323,10 +322,11 @@ class ShardedServeEngine:
             chunk_specs["patch_rows"] = P(ax, None, None)
             chunk_specs["n_patch"] = vec_spec
         self._chunk_specs = chunk_specs
-        self._chunk_jit = jax.jit(shard_map(
+        self._chunk_jit = jax.jit(jax.shard_map(
             _chunk, mesh=mesh,
             in_specs=(pspecs, chunk_specs, self._pool_specs),
-            out_specs=self._pool_specs), **donate)
+            out_specs=self._pool_specs, check_vma=False),
+            donate_argnums=(2,))
 
         def _cow(pools, src, dst):
             # COW tail clone, one (src, dst) pair per shard, LOCAL page
@@ -335,19 +335,18 @@ class ShardedServeEngine:
             return {k: p.at[:, dst[0]].set(p[:, src[0]])
                     for k, p in pools.items()}
 
-        cow_donate = {} if jax.default_backend() == "cpu" else \
-            {"donate_argnums": (0,)}
-        self._cow_jit = jax.jit(shard_map(
+        self._cow_jit = jax.jit(jax.shard_map(
             _cow, mesh=mesh,
             in_specs=(self._pool_specs, vec_spec, vec_spec),
-            out_specs=self._pool_specs), **cow_donate)
+            out_specs=self._pool_specs, check_vma=False),
+            donate_argnums=(0,))
 
         # move_pool_pages (PR 9): one wave moves up to `wave_moves` pages
         # per shard between device-local pools. Each shard snapshots its
         # exports (outbox) BEFORE any write, the outboxes cross the mesh in
         # ONE all_gather — the modeled UCIe transfer — and each shard
         # scatters its imports into freshly-allocated local pages. Pools
-        # move their NATIVE bytes: an int8 pool's int8 rows + f16 scales
+        # move their NATIVE bytes: an int8 pool's int8 rows + bf16 scales
         # are its block-compressed wire format (half the bf16 bytes), so
         # migrated pages stay bit-exact. Unused rows are 0 on both sides —
         # exporting and importing the null page are no-ops by contract.
@@ -363,10 +362,11 @@ class ShardedServeEngine:
             return pools
 
         mspec = P(ax, None)
-        self._move_jit = jax.jit(shard_map(
+        self._move_jit = jax.jit(jax.shard_map(
             _move, mesh=mesh,
             in_specs=(self._pool_specs, mspec, mspec, mspec, mspec),
-            out_specs=self._pool_specs), **cow_donate)
+            out_specs=self._pool_specs, check_vma=False),
+            donate_argnums=(0,))
         self._page_bytes = page_payload_bytes(self._pools)
         # retrace-sanitizer labels (analysis/sanitizer): the sharded engine
         # shares the single-host labels so COMPILE_BUDGETS apply unchanged,

@@ -61,8 +61,7 @@ def compress_decompress(grads, error_state=None, *, block: int = 256):
 def _ring_allreduce_int8(x: jnp.ndarray, axis_name: str, block: int = 256):
     """Inside shard_map: reduce-scatter + all-gather ring where every hop
     moves int8 blocks + f32 scales instead of f32 values."""
-    from repro.parallel.shmap import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     me = jax.lax.axis_index(axis_name)                 # traced device index

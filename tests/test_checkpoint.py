@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.train.checkpoint import CheckpointManager
 
@@ -112,11 +113,6 @@ else:
 
 # --- property-based: arbitrary pytrees roundtrip --------------------------
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container lacks hypothesis: fixed examples
-    st = None
-
 
 def _roundtrip(tree):
     import tempfile
@@ -136,24 +132,16 @@ _LEAVES = [
     jnp.float32(3.5),
 ]
 
-if st is not None:
-    settings.register_profile("ci", max_examples=15, deadline=None)
-    settings.load_profile("ci")
+settings.register_profile("ci", max_examples=15, deadline=None)
+settings.load_profile("ci")
 
-    _leaf = st.sampled_from(_LEAVES)
-    _tree_st = st.recursive(
-        _leaf, lambda kids: st.dictionaries(
-            st.sampled_from(["a", "b", "c", "w"]), kids, min_size=1, max_size=3),
-        max_leaves=6)
+_leaf = st.sampled_from(_LEAVES)
+_tree_st = st.recursive(
+    _leaf, lambda kids: st.dictionaries(
+        st.sampled_from(["a", "b", "c", "w"]), kids, min_size=1, max_size=3),
+    max_leaves=6)
 
-    @given(tree=_tree_st)
-    def test_roundtrip_arbitrary_pytrees(tree):
-        _roundtrip(tree)
-else:
-    @pytest.mark.parametrize("tree", [
-        _LEAVES[0],
-        {"a": _LEAVES[1], "b": _LEAVES[2]},
-        {"w": {"a": _LEAVES[3], "c": _LEAVES[0]}, "b": _LEAVES[1]},
-    ])
-    def test_roundtrip_arbitrary_pytrees(tree):
-        _roundtrip(tree)
+
+@given(tree=_tree_st)
+def test_roundtrip_arbitrary_pytrees(tree):
+    _roundtrip(tree)

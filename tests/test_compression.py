@@ -79,7 +79,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.train.compression import compressed_ring_allreduce
-from repro.parallel.shmap import shard_map
 
 mesh = make_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.key(0), (8, 1024), jnp.float32) * 0.1
@@ -87,15 +86,15 @@ x = jax.random.normal(jax.random.key(0), (8, 1024), jnp.float32) * 0.1
 def f(xs):
     return compressed_ring_allreduce(xs[0], "data")[None]
 
-y = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data", None),
-                          out_specs=P("data", None), check_vma=False))(x)
+y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data", None),
+                              out_specs=P("data", None), check_vma=False))(x)
 want = jnp.sum(x, axis=0)
 got = np.asarray(y[0])
 scale = float(jnp.max(jnp.abs(x)))
 assert np.abs(got - np.asarray(want)).max() < scale / 127.0 * 8 * 1.5, \
     np.abs(got - np.asarray(want)).max()
-txt = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data", None),
-                            out_specs=P("data", None), check_vma=False)).lower(x).compile().as_text()
+txt = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data", None),
+                                out_specs=P("data", None), check_vma=False)).lower(x).compile().as_text()
 import re
 perms = re.findall(r"(s8|f32|bf16)\[([0-9,]+)\][^\n]*collective-permute", txt)
 assert any(dt == "s8" for dt, _ in perms), perms

@@ -128,11 +128,15 @@ def test_qeinsum_pallas_vmaps_expert_weights():
 
 
 def test_qeinsum_pallas_falls_back_on_unfit_shapes():
-    """N not divisible by the clamped block must fall back to jnp (not
-    crash inside the kernel's asserts)."""
-    x = jax.random.normal(jax.random.key(6), (2, 4, 128), jnp.float32)
+    """A shape whose only tile-legal blocks (whole non-128-multiple K and N)
+    overflow the kernel's VMEM budget must fall back to jnp off-TPU (not
+    crash inside the kernel's asserts); on TPU the refusal raises."""
+    from repro.kernels.int8_matmul import pick_blocks
+    x = jax.random.normal(jax.random.key(6), (2, 4, 2600), jnp.float32)
     w = quantize_weight_channelwise(
-        jax.random.normal(jax.random.key(7), (128, 4, 40), jnp.float32), (0,))
+        jax.random.normal(jax.random.key(7), (2600, 4, 650), jnp.float32),
+        (0,))
+    assert pick_blocks(8, 2600, 2600, 4) is None
     got = qeinsum("bsd,dhk->bshk", x, w, impl="pallas", interpret=True)
     want = qeinsum("bsd,dhk->bshk", x, w, impl="jnp")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -244,7 +248,7 @@ def test_int8_token_divergence_bounded(arch, tol):
 
 # -------------------------------------------------------------- memory + API
 def test_int8_kv_pool_bytes_vs_bf16(smol):
-    """The acceptance ratio: int8 pool (int8 rows + f16 row scales + table)
+    """The acceptance ratio: int8 pool (int8 rows + bf16 row scales + table)
     <= ~0.55x the bf16 pool, same paging geometry."""
     cfg, model, params, _ = smol
     kw = dict(n_slots=4, max_len=64, params=params, page_size=8)

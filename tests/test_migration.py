@@ -8,7 +8,7 @@ These tests pin:
 
   * mid-decode migration is TOKEN-EXACT vs a stay-put twin across
     dense/moe/mla × {f32, int8} — the data path moves pool-native bytes
-    (an int8 pool's int8 rows + f16 scales ARE its block-compressed wire
+    (an int8 pool's int8 rows + bf16 scales ARE its block-compressed wire
     format), so migrated streams are bit-identical;
   * drain-via-migration emits the same tokens as drain-via-replay AND the
     fault-free twin, at ZERO extra prefill chunks (the O(bytes) vs O(FLOPs)
