@@ -443,6 +443,12 @@ def set_pool_page(cache, dst, rows):
     return c
 
 
+def _put_layer(pools, layer_cache, i):
+    """Write layer `i`'s updated pools back into the layer-stacked ones."""
+    return {key: jax.lax.dynamic_update_index_in_dim(
+        val, layer_cache[key], i, 0) for key, val in pools.items()}
+
+
 def prefill_chunk(params, batch, cache, cfg, opts: ExecOptions):
     """One fixed-size chunk of page-granular prefill (PR 4).
 
@@ -495,11 +501,12 @@ def prefill_chunk(params, batch, cache, cfg, opts: ExecOptions):
     def body(carry, xs):
         h, pools = carry
         lp, i = xs
-        layer_cache = {key: dyn(val, i) for key, val in pools.items()}
+        with jax.named_scope("kv_pool"):
+            layer_cache = {key: dyn(val, i) for key, val in pools.items()}
         h, new_cache = layer_fn(h, lp, cfg, opts, positions=positions,
                                 mode="chunk", cache=layer_cache, chunk=chunk)
-        pools = {key: jax.lax.dynamic_update_index_in_dim(
-            val, new_cache[key], i, 0) for key, val in pools.items()}
+        with jax.named_scope("kv_pool"):
+            pools = _put_layer(pools, new_cache, i)
         return (h, pools), None
 
     from repro.models.common import scan_or_unroll
@@ -523,16 +530,18 @@ def layer_fn(x, lp, cfg, opts: ExecOptions, *, positions, mode,
              cache: Optional[dict] = None, kv_round=None, chunk=None):
     c = opts.constrain
     x = c(x, "batchlike", opts.seq_axis, None)
-    a, new_cache = attn_block(
-        rms_norm(x, lp["attn_norm"], plus_one=cfg.norm_plus_one),
-        lp, cfg, opts, positions=positions, mode=mode, cache=cache,
-        kv_round=kv_round, chunk=chunk)
+    h = rms_norm(x, lp["attn_norm"], plus_one=cfg.norm_plus_one)
+    with jax.named_scope("attn"):
+        a, new_cache = attn_block(
+            h, lp, cfg, opts, positions=positions, mode=mode, cache=cache,
+            kv_round=kv_round, chunk=chunk)
     x = x + a
     h = rms_norm(x, lp["ffn_norm"], plus_one=cfg.norm_plus_one)
-    if cfg.family == "moe":
-        f = moe_mod.moe_ffn(h, lp, _maybe_group(cfg, opts), constrain=c)
-    else:
-        f = dense_ffn(h, lp, cfg, opts)
+    with jax.named_scope("mlp"):
+        if cfg.family == "moe":
+            f = moe_mod.moe_ffn(h, lp, _maybe_group(cfg, opts), constrain=c)
+        else:
+            f = dense_ffn(h, lp, cfg, opts)
     return x + f, new_cache
 
 
@@ -664,8 +673,9 @@ def prefill(params, batch, cfg, opts: ExecOptions):
                                 patch_embeds=batch.get("patch_embeds"),
                                 mode="prefill", kv_round=_kv_round_of(batch))
     last = hidden[:, -1:, :]
-    logits = jnp.einsum("bsd,vd->bsv", last, lm_head_weights(params, cfg))
-    logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", last, lm_head_weights(params, cfg))
+        logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
     b, s = batch["tokens"].shape
     return logits, dict(kv, pos=jnp.full((b,), s, jnp.int32))
 
@@ -687,14 +697,15 @@ def decode_step(params, batch, cache, cfg, opts: ExecOptions):
     def body(carry, xs):
         h, pools = carry
         lp, i = xs
-        layer_cache = {key: dyn(val, i) for key, val in pools.items()}
+        with jax.named_scope("kv_pool"):
+            layer_cache = {key: dyn(val, i) for key, val in pools.items()}
         if page_table is not None:
             layer_cache["page_table"] = page_table
         h, new_cache = layer_fn(h, lp, cfg, opts,
                                 positions=positions[:, None], mode="decode",
                                 cache=layer_cache)
-        pools = {key: jax.lax.dynamic_update_index_in_dim(
-            val, new_cache[key], i, 0) for key, val in pools.items()}
+        with jax.named_scope("kv_pool"):
+            pools = _put_layer(pools, new_cache, i)
         return (h, pools), None
 
     from repro.models.common import scan_or_unroll
@@ -703,8 +714,9 @@ def decode_step(params, batch, cache, cfg, opts: ExecOptions):
         (params["layers"], jnp.arange(cfg.n_layers)),
         unroll=opts.unroll_scans)
     x = rms_norm(x, params["final_norm"], plus_one=cfg.norm_plus_one)
-    logits = jnp.einsum("bsd,vd->bsv", x, lm_head_weights(params, cfg))
-    logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x, lm_head_weights(params, cfg))
+        logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
     new_cache = dict(pools, pos=positions + 1)
     if page_table is not None:
         new_cache["page_table"] = page_table

@@ -129,11 +129,12 @@ import hashlib
 import math
 import time
 from collections import OrderedDict
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.sanitizer import register_entry_point
 from repro.models.quantized import quantize_kv_rows
@@ -337,7 +338,15 @@ class Request:
     logit_bias: Optional[Dict[int, float]] = None
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # lifecycle stamps, all on one monotonic clock (time.perf_counter), so
+    # only differences mean anything. A preempted and resumed request keeps
+    # its first stamps. The prefill pair brackets the request's own chunks
+    # (or its monolithic prefill); a request whose whole prompt was served
+    # from the prefix cache runs no chunk and has no t_prefill_start.
     t_enqueue: float = 0.0
+    t_admitted: Optional[float] = None       # first admission into a slot
+    t_prefill_start: Optional[float] = None  # before its first prefill
+    t_prefill_end: Optional[float] = None    # slot goes live (finalize)
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
     # tick-domain latency (deterministic twin of the wall-clock fields: the
@@ -375,7 +384,6 @@ class EngineStats:
     occupancy_sum: float = 0.0
     prefill_compiles: int = 0   # actual jit traces (bucketing keeps this flat)
     decode_compiles: int = 0
-    paste_compiles: int = 0
     chunk_compiles: int = 0     # chunked prefill: ONE total (fixed shapes)
     prefill_chunks: int = 0     # chunk-prefill invocations
     pages_in_use: int = 0       # paged engines: currently reserved pages
@@ -544,6 +552,16 @@ def _make_paste_paged(fam: str):
         return c
 
     return paste
+
+
+class _PagePlan(NamedTuple):
+    """How an admission takes pages (`ServeEngine._plan_pages`)."""
+    hits: List[int]               # cached pages of the prompt, in order
+    digs: Optional[List[bytes]]   # its full pages' digests, where it misses
+    n_shared: int                 # hit pages mapped outright
+    cow_src: Optional[int]        # hit tail page cloned copy-on-write
+    n_private: int                # pages to allocate
+    cached: int                   # prompt tokens served from the cache
 
 
 class ServeEngine:
@@ -776,10 +794,7 @@ class ServeEngine:
             return toks, new_cache
 
         if self.paged:
-            def _paste(cache, pf, slot, pos, page_row):
-                self.stats.paste_compiles += 1
-                return _make_paste_paged(self.cfg.family)(
-                    cache, pf, slot, pos, page_row)
+            _paste = _make_paste_paged(self.cfg.family)
 
             def _unmap(cache, slot):
                 # retired slot: point its whole table row at the null page so
@@ -836,9 +851,7 @@ class ServeEngine:
                     self._paste_cross_jit = jax.jit(_paste_cross,
                                                     donate_argnums=(0,))
         else:
-            def _paste(cache, pf, slot, pos):
-                self.stats.paste_compiles += 1
-                return _make_paste(self.cfg.family)(cache, pf, slot, pos)
+            _paste = _make_paste(self.cfg.family)
 
         self._prefill_jit = jax.jit(_prefill)
         self._decode_jit = jax.jit(_decode, donate_argnums=(2,))
@@ -942,7 +955,7 @@ class ServeEngine:
                       top_p=float(top_p), seed=int(seed),
                       rep_penalty=rep_penalty,
                       logit_bias=dict(logit_bias) if logit_bias else None,
-                      t_enqueue=time.time(),
+                      t_enqueue=time.perf_counter(),
                       submit_tick=self._tick, ttl_ticks=ttl_ticks)
         if ttl_ticks is not None:
             self._any_ttl = True
@@ -978,6 +991,37 @@ class ServeEngine:
         return sum(x.size * x.dtype.itemsize
                    for x in jax.tree.leaves(self._cache))
 
+    def programs(self) -> Dict[str, Tuple[Callable, tuple]]:
+        """The jitted programs the tick loop runs, each with abstract
+        arguments shaped and placed as `step()` feeds them:
+        {"decode": (fn, args), "chunk": (fn, args)}, so that
+        `fn.lower(*args)` is the program the loop dispatches. "chunk" is
+        there only for engines with chunked prefill."""
+        def spec(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+
+        def arg(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        params = jax.tree.map(spec, self.params)
+        cache = jax.tree.map(spec, self._cache)
+        n = self.n_slots
+        progs = {"decode": (self._decode_jit, (
+            params, {"tokens": arg((n, 1))}, cache, arg((n,), jnp.bool_)))}
+        if self.chunked:
+            C = self.chunk_tokens
+            batch = {"tokens": arg((1, C)), "start": arg((1,)),
+                     "length": arg((1,)),
+                     "page_row": arg((self.pages_per_seq,))}
+            if self.cfg.family == "vlm":
+                batch["patch_rows"] = arg((1, C, self.cfg.d_model),
+                                          jnp.float32)
+                batch["n_patch"] = arg((1,))
+            if self.cfg.family == "encdec":
+                batch["slot"] = arg(())
+            progs["chunk"] = (self._chunk_jit, (params, batch, cache))
+        return progs
+
     def _sample_state(self, slot: int, r: Request):
         self._temp[slot] = r.temperature
         self._topk[slot] = r.top_k
@@ -1010,189 +1054,222 @@ class ServeEngine:
         (min(max_len, (plen+k) + (max_new-k)) == min(max_len, plen+max_new)),
         so a preempted request never needs more pages than it first did."""
         self._page_blocked = False
-        for slot in [i for i, r in enumerate(self._slots) if r is None]:
-            if not self._queue:
-                return
-            r = self._queue[0]
-            lp = r.live_prompt()
-            plen = lp.shape[0]
-            rem = r.remaining_new()
-            page_row = None
-            r.cached_prompt_tokens = 0
-            if self.paged:
-                need = self._pages_for(plen, rem)
-                hits, _ = self._prefix_lookup(r, lp)
-                digs = None
-                n_cand = plen // self.page_size if self.prefix_cache else 0
-                if len(hits) < n_cand:
-                    digs = prefix_digests(lp, self.page_size, n_cand,
-                                          request_seed_digest(r.extras))
-                    owner = self._pending_digest.get(digs[len(hits)])
-                    if owner is not None and owner != r.rid:
-                        # in-flight dedup: the head's first missing page is
-                        # being prefilled by a live slot right now — hold
-                        # admission (FIFO) and hit the registry once it
-                        # lands instead of prefilling the same bytes twice.
-                        # NOT a page starvation: no preemption pressure.
-                        return
-                n_shared, cow_src = self._share_plan(plen, hits)
-                shared = hits[:n_shared]
-                n_private = need - n_shared
-                # hit pages resident in the LRU leave the allocatable set
-                # the instant we incref them — account for that BEFORE
-                # committing (cow_src is pinned during the clone, so it
-                # counts too)
-                pinned = sum(1 for p in shared if self._ref[p] == 0)
-                if cow_src is not None and self._ref[cow_src] == 0:
-                    pinned += 1
-                if self._allocatable() - pinned < n_private:
-                    # head starved on pages while a slot sits free: the
-                    # signal step() counts toward preemption
-                    self._page_blocked = True
+        with TraceAnnotation("engine.admit"):
+            for slot in [i for i, r in enumerate(self._slots) if r is None]:
+                if not self._queue:
                     return
-                # commit order: protect the hit pages FIRST (incref pulls
-                # them out of the eviction set), then allocate privates
-                for p in shared:
-                    self._incref(p)
-                if cow_src is not None:
-                    self._incref(cow_src)
-                pages = [self._alloc_page() for _ in range(n_private)]
-                if cow_src is not None:
-                    # copy-on-write: the replay decode WRITES position
-                    # plen-1, so a fully-cached tail page is cloned into
-                    # the slot's first private page instead of recomputed
-                    self.stats.cow_copies += 1
-                    self._cache = self._cow_jit(
-                        self._cache, jnp.int32(cow_src),
-                        jnp.int32(pages[0]))
-                    self._decref_page(cow_src)
-                lo = self._live_lo(plen) \
-                    if (self._window and not self.chunked) else 0
-                mapping = {j: p for j, p in enumerate(shared)}
-                for i, p in enumerate(pages):
-                    mapping[lo + n_shared + i] = p
-                self._slot_pages[slot] = mapping
-                self._slot_cap[slot] = -(-min(self.max_len, plen + rem)
-                                         // self.page_size)
-                cached = (n_shared + (cow_src is not None)) * self.page_size
-                r.cached_prompt_tokens = cached
-                if self.prefix_cache:
-                    if cached:
-                        self.stats.prefix_hits += 1
-                        self.stats.prefix_hit_tokens += cached
-                    else:
-                        self.stats.prefix_misses += 1
-                page_row = np.zeros((self.pages_per_seq,), np.int32)
-                for j, p in mapping.items():
-                    page_row[j] = p
-            self._queue.pop(0)
-            self.stats.prefills += 1
-            self.stats.prefill_tokens += plen - r.cached_prompt_tokens
-            self._sample_state(slot, r)
-            if self.chunked:
-                # reserve-only admission: the slot's cache table row stays on
-                # the null page (decode's garbage writes can't touch reserved
-                # pages) until the final chunk stamps it in _prefill_tick
-                self._slots[slot] = r
-                self._active[slot] = False
-                self._fresh[slot] = False
-                self._chunk_next[slot] = r.cached_prompt_tokens
-                if self.model.prefill_cross is not None:
-                    cross = self._cross_jit(self.params, {
-                        "frames": jnp.asarray(r.extras["frames"])[None]})
-                    self._cache = self._paste_cross_jit(
-                        self._cache, cross["ck"], cross["cv"],
-                        jnp.int32(slot))
-                if r.cached_prompt_tokens >= plen:
-                    # FULL hit: every prompt page is already in the pool
-                    # (shared run + COW-cloned tail) — zero prefill chunks.
-                    # The slot goes live immediately and its first token
-                    # arrives from THIS tick's decode: TTFT collapses to
-                    # one decode step
-                    self._register_prefix(slot, r, lp)
-                    self._cache = self._finalize_jit(
-                        self._cache, jnp.int32(slot), jnp.int32(plen - 1),
-                        jnp.asarray(page_row))
-                    self._next_tok[slot, 0] = int(lp[-1])
-                    self._fresh[slot] = True
-                    self._active[slot] = True
+                r = self._queue[0]
+                lp = r.live_prompt()
+                plan = self._plan_pages(r, lp) if self.paged else None
+                if self.paged and plan is None:
+                    return
+                with TraceAnnotation(
+                        "engine.admit_request", rid=r.rid, slot=slot,
+                        prompt_tokens=lp.shape[0],
+                        cached_tokens=0 if plan is None else plan.cached):
+                    self._admit_request(slot, r, lp, plan)
+
+    def _plan_pages(self, r: Request,
+                    lp: np.ndarray) -> Optional[_PagePlan]:
+        """How the queue head `r` would take pages (paged engines), or None
+        when it must wait: an identical prompt's pages are being prefilled
+        right now, or the pool cannot cover it (`_page_blocked` set)."""
+        plen = lp.shape[0]
+        need = self._pages_for(plen, r.remaining_new())
+        hits, _ = self._prefix_lookup(r, lp)
+        digs = None
+        n_cand = plen // self.page_size if self.prefix_cache else 0
+        if len(hits) < n_cand:
+            digs = prefix_digests(lp, self.page_size, n_cand,
+                                  request_seed_digest(r.extras))
+            owner = self._pending_digest.get(digs[len(hits)])
+            if owner is not None and owner != r.rid:
+                # in-flight dedup: the head's first missing page is
+                # being prefilled by a live slot right now — hold
+                # admission (FIFO) and hit the registry once it
+                # lands instead of prefilling the same bytes twice.
+                # NOT a page starvation: no preemption pressure.
+                return None
+        n_shared, cow_src = self._share_plan(plen, hits)
+        n_private = need - n_shared
+        # hit pages resident in the LRU leave the allocatable set
+        # the instant we incref them — account for that BEFORE
+        # committing (cow_src is pinned during the clone, so it
+        # counts too)
+        pinned = sum(1 for p in hits[:n_shared] if self._ref[p] == 0)
+        if cow_src is not None and self._ref[cow_src] == 0:
+            pinned += 1
+        if self._allocatable() - pinned < n_private:
+            # head starved on pages while a slot sits free: the
+            # signal step() counts toward preemption
+            self._page_blocked = True
+            return None
+        return _PagePlan(hits, digs, n_shared, cow_src, n_private,
+                         (n_shared + (cow_src is not None)) * self.page_size)
+
+    def _admit_request(self, slot: int, r: Request, lp: np.ndarray,
+                       plan: Optional[_PagePlan]) -> None:
+        """Move the queue head `r` into free `slot`, committing `plan`."""
+        plen = lp.shape[0]
+        rem = r.remaining_new()
+        page_row = None
+        digs = None
+        r.cached_prompt_tokens = 0
+        if r.t_admitted is None:
+            r.t_admitted = time.perf_counter()
+        if plan is not None:
+            digs, n_shared, cow_src = plan.digs, plan.n_shared, plan.cow_src
+            shared = plan.hits[:n_shared]
+            # commit order: protect the hit pages FIRST (incref pulls
+            # them out of the eviction set), then allocate privates
+            for p in shared:
+                self._incref(p)
+            if cow_src is not None:
+                self._incref(cow_src)
+            pages = [self._alloc_page() for _ in range(plan.n_private)]
+            if cow_src is not None:
+                # copy-on-write: the replay decode WRITES position
+                # plen-1, so a fully-cached tail page is cloned into
+                # the slot's first private page instead of recomputed
+                self.stats.cow_copies += 1
+                self._cache = self._cow_jit(
+                    self._cache, jnp.int32(cow_src),
+                    jnp.int32(pages[0]))
+                self._decref_page(cow_src)
+            lo = self._live_lo(plen) \
+                if (self._window and not self.chunked) else 0
+            mapping = {j: p for j, p in enumerate(shared)}
+            for i, p in enumerate(pages):
+                mapping[lo + n_shared + i] = p
+            self._slot_pages[slot] = mapping
+            self._slot_cap[slot] = -(-min(self.max_len, plen + rem)
+                                     // self.page_size)
+            r.cached_prompt_tokens = plan.cached
+            if self.prefix_cache:
+                if plan.cached:
+                    self.stats.prefix_hits += 1
+                    self.stats.prefix_hit_tokens += plan.cached
                 else:
-                    self._prefill_fifo.append(slot)
-                    if digs is not None:
-                        # claim the pages this slot will register, so
-                        # identical prompts behind it wait for the cache
-                        mine = self._pending_by_rid.setdefault(r.rid, [])
-                        for d in digs[len(hits):]:
-                            if d not in self._pending_digest:
-                                self._pending_digest[d] = r.rid
-                                mine.append(d)
-                continue
-            blen = bucket_length(plen, self.max_len) if self.bucket_prompts \
-                else plen
-            toks = np.zeros((1, blen), np.int32)
-            toks[0, :plen] = lp
-            batch = {"tokens": jnp.asarray(toks)}
-            if self.kv_dtype != jnp.float32:
-                # lossy KV storage: prefill attends the rounded values the
-                # cache will hold (zero-size marker, dtype carries the info)
-                batch["kv_round"] = jnp.zeros((0,), self.kv_dtype)
-            for key, val in (r.extras or {}).items():
-                batch[key] = jnp.asarray(val)[None]
-            logits, pf_cache = self._prefill_jit(self.params, batch)
-            self.stats.prefill_pad_tokens += blen - plen
-            self._tick_prefill_tokens += blen
-            paste_args = () if page_row is None else (jnp.asarray(page_row),)
-            if self._replay:
-                # Cache rows [0, plen) are exact under trailing padding; the
-                # next decode step replays prompt[-1] at position plen-1,
-                # producing the first output token through the decode path
-                # (pad rows ≥ plen are masked by kv_len until overwritten).
-                self._cache = self._paste_jit(
-                    self._cache, pf_cache, jnp.int32(slot),
-                    jnp.int32(plen - 1), *paste_args)
-                self._next_tok[slot, 0] = int(lp[-1])
-            else:
-                lv = jnp.asarray(logits[:, -1, :self.cfg.vocab_size],
-                                 jnp.float32)
-                if r.rep_penalty != 1.0 or r.logit_bias:
-                    # non-replay first token: processors apply here too —
-                    # _sample_state already loaded this slot's seen/bias rows
-                    lv = self._proc1_jit(
-                        lv, jnp.full((1,), r.rep_penalty, jnp.float32),
-                        jnp.asarray(self._seen[slot][None]),
-                        jnp.asarray(self._bias[slot][None]))
-                if r.temperature > 0:
-                    first = int(np.asarray(self._sample1_jit(
-                        lv, jnp.full((1,), r.temperature, jnp.float32),
-                        jnp.full((1,), r.top_k, jnp.int32),
-                        jnp.full((1,), r.top_p, jnp.float32),
-                        jnp.full((1,), r.seed, jnp.int32),
-                        jnp.zeros((1,), jnp.int32)))[0])
-                else:
-                    first = int(np.argmax(np.asarray(lv[0])))
-                self._cache = self._paste_jit(
-                    self._cache, pf_cache, jnp.int32(slot), jnp.int32(plen),
-                    *paste_args)
-                r.out_tokens.append(first)
-                if r.t_first_token is None:
-                    r.t_first_token = time.time()
-                    r.first_token_tick = self._tick
-                self._next_tok[slot, 0] = first
-                self._seen[slot, first] = True
-                self.stats.tokens_out += 1
-                if plen >= self.max_len \
-                        or len(r.out_tokens) >= r.max_new_tokens:
-                    # done at admission: the cache is already full (no
-                    # writable row for a decode step) or the prefill token
-                    # exhausted the budget — never occupy a decode slot
-                    r.done = True
-                    r.t_done = time.time()
-                    self.stats.record_request(r)
-                    self._release(slot)
-                    continue
-            self._fresh[slot] = self._replay
+                    self.stats.prefix_misses += 1
+            page_row = np.zeros((self.pages_per_seq,), np.int32)
+            for j, p in mapping.items():
+                page_row[j] = p
+        self._queue.pop(0)
+        self.stats.prefills += 1
+        self.stats.prefill_tokens += plen - r.cached_prompt_tokens
+        self._sample_state(slot, r)
+        if self.chunked:
+            # reserve-only admission: the slot's cache table row stays on
+            # the null page (decode's garbage writes can't touch reserved
+            # pages) until the final chunk stamps it in _prefill_tick
             self._slots[slot] = r
-            self._active[slot] = True
+            self._active[slot] = False
+            self._fresh[slot] = False
+            self._chunk_next[slot] = r.cached_prompt_tokens
+            if self.model.prefill_cross is not None:
+                cross = self._cross_jit(self.params, {
+                    "frames": jnp.asarray(r.extras["frames"])[None]})
+                self._cache = self._paste_cross_jit(
+                    self._cache, cross["ck"], cross["cv"],
+                    jnp.int32(slot))
+            if r.cached_prompt_tokens >= plen:
+                # FULL hit: every prompt page is already in the pool
+                # (shared run + COW-cloned tail) — zero prefill chunks.
+                # The slot goes live immediately and its first token
+                # arrives from THIS tick's decode: TTFT collapses to
+                # one decode step
+                self._register_prefix(slot, r, lp)
+                self._cache = self._finalize_jit(
+                    self._cache, jnp.int32(slot), jnp.int32(plen - 1),
+                    jnp.asarray(page_row))
+                if r.t_prefill_end is None:
+                    r.t_prefill_end = time.perf_counter()
+                self._next_tok[slot, 0] = int(lp[-1])
+                self._fresh[slot] = True
+                self._active[slot] = True
+            else:
+                self._prefill_fifo.append(slot)
+                if digs is not None:
+                    # claim the pages this slot will register, so
+                    # identical prompts behind it wait for the cache
+                    mine = self._pending_by_rid.setdefault(r.rid, [])
+                    for d in digs[len(plan.hits):]:
+                        if d not in self._pending_digest:
+                            self._pending_digest[d] = r.rid
+                            mine.append(d)
+            return
+        blen = bucket_length(plen, self.max_len) if self.bucket_prompts \
+            else plen
+        toks = np.zeros((1, blen), np.int32)
+        toks[0, :plen] = lp
+        batch = {"tokens": jnp.asarray(toks)}
+        if self.kv_dtype != jnp.float32:
+            # lossy KV storage: prefill attends the rounded values the
+            # cache will hold (zero-size marker, dtype carries the info)
+            batch["kv_round"] = jnp.zeros((0,), self.kv_dtype)
+        for key, val in (r.extras or {}).items():
+            batch[key] = jnp.asarray(val)[None]
+        if r.t_prefill_start is None:
+            r.t_prefill_start = time.perf_counter()
+        logits, pf_cache = self._prefill_jit(self.params, batch)
+        self.stats.prefill_pad_tokens += blen - plen
+        self._tick_prefill_tokens += blen
+        paste_args = () if page_row is None else (jnp.asarray(page_row),)
+        if self._replay:
+            # Cache rows [0, plen) are exact under trailing padding; the
+            # next decode step replays prompt[-1] at position plen-1,
+            # producing the first output token through the decode path
+            # (pad rows ≥ plen are masked by kv_len until overwritten).
+            self._cache = self._paste_jit(
+                self._cache, pf_cache, jnp.int32(slot),
+                jnp.int32(plen - 1), *paste_args)
+            if r.t_prefill_end is None:
+                r.t_prefill_end = time.perf_counter()
+            self._next_tok[slot, 0] = int(lp[-1])
+        else:
+            lv = jnp.asarray(logits[:, -1, :self.cfg.vocab_size],
+                             jnp.float32)
+            if r.rep_penalty != 1.0 or r.logit_bias:
+                # non-replay first token: processors apply here too —
+                # _sample_state already loaded this slot's seen/bias rows
+                lv = self._proc1_jit(
+                    lv, jnp.full((1,), r.rep_penalty, jnp.float32),
+                    jnp.asarray(self._seen[slot][None]),
+                    jnp.asarray(self._bias[slot][None]))
+            if r.temperature > 0:
+                first = int(np.asarray(self._sample1_jit(
+                    lv, jnp.full((1,), r.temperature, jnp.float32),
+                    jnp.full((1,), r.top_k, jnp.int32),
+                    jnp.full((1,), r.top_p, jnp.float32),
+                    jnp.full((1,), r.seed, jnp.int32),
+                    jnp.zeros((1,), jnp.int32)))[0])
+            else:
+                first = int(np.argmax(np.asarray(lv[0])))
+            self._cache = self._paste_jit(
+                self._cache, pf_cache, jnp.int32(slot), jnp.int32(plen),
+                *paste_args)
+            if r.t_prefill_end is None:
+                r.t_prefill_end = time.perf_counter()
+            r.out_tokens.append(first)
+            if r.t_first_token is None:
+                r.t_first_token = time.perf_counter()
+                r.first_token_tick = self._tick
+            self._next_tok[slot, 0] = first
+            self._seen[slot, first] = True
+            self.stats.tokens_out += 1
+            if plen >= self.max_len \
+                    or len(r.out_tokens) >= r.max_new_tokens:
+                # done at admission: the cache is already full (no
+                # writable row for a decode step) or the prefill token
+                # exhausted the budget — never occupy a decode slot
+                r.done = True
+                r.t_done = time.perf_counter()
+                self.stats.record_request(r)
+                self._release(slot)
+                return
+        self._fresh[slot] = self._replay
+        self._slots[slot] = r
+        self._active[slot] = True
 
     def cancel(self, req: Request) -> None:
         """Retire a request at ANY lifecycle stage with exact pool
@@ -1208,34 +1285,38 @@ class ServeEngine:
         elif req in self._slots:
             self._release(self._slots.index(req))
         req.done = True
-        req.t_done = time.time()
+        req.t_done = time.perf_counter()
 
     def _release(self, slot: int):
         """Return a finished slot to the pool and drain any queued prefill
         work it still holds (mid-prefill retirement must leak nothing)."""
-        if self._slots[slot] is not None:
-            self._clear_pending(self._slots[slot].rid)
-        self._slots[slot] = None
-        self._active[slot] = False
-        self._fresh[slot] = False
-        self._temp[slot], self._topk[slot] = 0.0, 0
-        self._topp[slot], self._sseed[slot] = 1.0, 0
-        self._rep_pen[slot] = 1.0
-        self._bias[slot], self._bias_on[slot] = 0.0, False
-        self._seen[slot] = False
-        if slot in self._prefill_fifo:          # mid-prefill: drain chunks
-            self._prefill_fifo.remove(slot)
-        if self.chunked:
-            self._chunk_next[slot] = 0
-        if self.paged:
-            freed = self._slot_pages[slot]
-            if freed:
-                # slots hold REFERENCES: a shared page survives its
-                # releasing slot and only leaves the live set at refcount 0
-                for phys in freed.values():
-                    self._decref_page(phys)
-                self._slot_pages[slot] = {}
-            self._cache = self._unmap_jit(self._cache, jnp.int32(slot))
+        r = self._slots[slot]
+        with TraceAnnotation("engine.release",
+                             rid=-1 if r is None else r.rid, slot=slot):
+            if r is not None:
+                self._clear_pending(r.rid)
+            self._slots[slot] = None
+            self._active[slot] = False
+            self._fresh[slot] = False
+            self._temp[slot], self._topk[slot] = 0.0, 0
+            self._topp[slot], self._sseed[slot] = 1.0, 0
+            self._rep_pen[slot] = 1.0
+            self._bias[slot], self._bias_on[slot] = 0.0, False
+            self._seen[slot] = False
+            if slot in self._prefill_fifo:      # mid-prefill: drain chunks
+                self._prefill_fifo.remove(slot)
+            if self.chunked:
+                self._chunk_next[slot] = 0
+            if self.paged:
+                freed = self._slot_pages[slot]
+                if freed:
+                    # slots hold REFERENCES: a shared page survives its
+                    # releasing slot and only leaves the live set at
+                    # refcount 0
+                    for phys in freed.values():
+                        self._decref_page(phys)
+                    self._slot_pages[slot] = {}
+                self._cache = self._unmap_jit(self._cache, jnp.int32(slot))
 
     # ------------------------------------- ref-counted page allocator (PR 8)
     def _allocatable(self) -> int:
@@ -1378,48 +1459,55 @@ class ServeEngine:
         lp = r.live_prompt()
         plen = lp.shape[0]
         C = self.chunk_tokens
-        if self._window and s:
-            # free/remap pages that no chunk row >= s can still read — a
-            # prompt longer than the window holds O(window) pages while
-            # prefilling; the cache table row is still null, so this is pure
-            # host bookkeeping until finalize stamps the row
-            self._recycle_slot_pages(slot, s, in_cache=False)
         n = min(C, plen - s)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :n] = lp[s:s + n]
-        page_row = self._page_row(slot)
-        batch = {"tokens": jnp.asarray(toks),
-                 "start": jnp.full((1,), s, jnp.int32),
-                 "length": jnp.full((1,), n, jnp.int32),
-                 "page_row": jnp.asarray(page_row)}
-        if self.cfg.family == "vlm":
-            pe = np.asarray((r.extras or {}).get(
-                "patch_embeds", np.zeros((0, self.cfg.d_model), np.float32)))
-            rows = np.zeros((1, C, self.cfg.d_model), np.float32)
-            if s < pe.shape[0]:
-                m = min(C, pe.shape[0] - s)
-                rows[0, :m] = pe[s:s + m]
-            batch["patch_rows"] = jnp.asarray(rows)
-            batch["n_patch"] = jnp.full((1,), pe.shape[0], jnp.int32)
-        if self.cfg.family == "encdec":
-            batch["slot"] = jnp.int32(slot)
-        self._cache = self._chunk_jit(self.params, batch, self._cache)
-        self.stats.prefill_chunks += 1
-        self.stats.prefill_pad_tokens += C - n
-        self._tick_prefill_tokens += C
-        if s + C >= plen:                      # final chunk — slot goes live
-            self._prefill_fifo.pop(0)
-            # the slot's full prompt pages are now byte-final: register them
-            # for prefix sharing before decode starts appending
-            self._register_prefix(slot, r, lp)
-            self._cache = self._finalize_jit(
-                self._cache, jnp.int32(slot), jnp.int32(plen - 1),
-                jnp.asarray(page_row))
-            self._next_tok[slot, 0] = int(lp[-1])
-            self._fresh[slot] = True
-            self._active[slot] = True
-        else:
-            self._chunk_next[slot] = s + C
+        with TraceAnnotation("engine.prefill_chunk", rid=r.rid, slot=slot,
+                             start=s, length=n):
+            if self._window and s:
+                # free/remap pages that no chunk row >= s can still read —
+                # a prompt longer than the window holds O(window) pages
+                # while prefilling; the cache table row is still null, so
+                # this is pure host bookkeeping until finalize stamps it
+                self._recycle_slot_pages(slot, s, in_cache=False)
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :n] = lp[s:s + n]
+            page_row = self._page_row(slot)
+            batch = {"tokens": jnp.asarray(toks),
+                     "start": jnp.full((1,), s, jnp.int32),
+                     "length": jnp.full((1,), n, jnp.int32),
+                     "page_row": jnp.asarray(page_row)}
+            if self.cfg.family == "vlm":
+                pe = np.asarray((r.extras or {}).get(
+                    "patch_embeds",
+                    np.zeros((0, self.cfg.d_model), np.float32)))
+                rows = np.zeros((1, C, self.cfg.d_model), np.float32)
+                if s < pe.shape[0]:
+                    m = min(C, pe.shape[0] - s)
+                    rows[0, :m] = pe[s:s + m]
+                batch["patch_rows"] = jnp.asarray(rows)
+                batch["n_patch"] = jnp.full((1,), pe.shape[0], jnp.int32)
+            if self.cfg.family == "encdec":
+                batch["slot"] = jnp.int32(slot)
+            if r.t_prefill_start is None:
+                r.t_prefill_start = time.perf_counter()
+            self._cache = self._chunk_jit(self.params, batch, self._cache)
+            self.stats.prefill_chunks += 1
+            self.stats.prefill_pad_tokens += C - n
+            self._tick_prefill_tokens += C
+            if s + C >= plen:                  # final chunk — slot goes live
+                self._prefill_fifo.pop(0)
+                # the slot's full prompt pages are now byte-final: register
+                # them for prefix sharing before decode starts appending
+                self._register_prefix(slot, r, lp)
+                self._cache = self._finalize_jit(
+                    self._cache, jnp.int32(slot), jnp.int32(plen - 1),
+                    jnp.asarray(page_row))
+                if r.t_prefill_end is None:
+                    r.t_prefill_end = time.perf_counter()
+                self._next_tok[slot, 0] = int(lp[-1])
+                self._fresh[slot] = True
+                self._active[slot] = True
+            else:
+                self._chunk_next[slot] = s + C
         return True
 
     # ----------------------------------------------------------------- decode
@@ -1427,31 +1515,78 @@ class ServeEngine:
         """One engine tick: apply scheduled faults, expire TTLs, admit new
         work (preempting a young decoding slot if the head has starved on
         pages), run at most one prefill chunk, then one batched decode step
-        over the live slots."""
+        over the live slots.
+
+        Each tick is one `engine.step` span in the profiler's trace (host
+        spans, `jax.profiler.TraceAnnotation`), with `engine.admit`,
+        `engine.prefill_chunk`, `engine.decode`, `engine.readback` and
+        `engine.release` nested inside it; they cost a few microseconds
+        when no trace is being taken."""
         self._tick += 1
-        if self.fault_plan is not None:
-            self._apply_faults()
-        if self._any_ttl:
-            self._expire_ttl()
-        had_decode = bool(np.any(self._active))
-        self._tick_prefill_tokens = 0
-        self._admit()
-        if self._page_blocked:
-            self._starved += 1
-            if self._starved >= self.preempt_after and self._preempt_once():
-                self._admit()
-        else:
-            self._starved = 0
-        chunk_ran = self._prefill_tick() if self.chunked else False
-        if had_decode and self._tick_prefill_tokens > self.chunk_tokens:
-            # decode batch waited on more than one chunk's worth of prefill
-            # this tick — the head-of-line blocking chunking eliminates
-            self.stats.decode_stall_ticks += \
-                -(-self._tick_prefill_tokens // self.chunk_tokens) - 1
-        decoding = [i for i, r in enumerate(self._slots)
-                    if r is not None and self._active[i]]
-        if not decoding:
-            return chunk_ran
+        with TraceAnnotation("engine.step", tick=self._tick):
+            if self.fault_plan is not None:
+                self._apply_faults()
+            if self._any_ttl:
+                self._expire_ttl()
+            had_decode = bool(np.any(self._active))
+            self._tick_prefill_tokens = 0
+            self._admit()
+            if self._page_blocked:
+                self._starved += 1
+                if self._starved >= self.preempt_after \
+                        and self._preempt_once():
+                    self._admit()
+            else:
+                self._starved = 0
+            chunk_ran = self._prefill_tick() if self.chunked else False
+            if had_decode and self._tick_prefill_tokens > self.chunk_tokens:
+                # decode batch waited on more than one chunk's worth of
+                # prefill this tick — the head-of-line blocking chunking
+                # eliminates
+                self.stats.decode_stall_ticks += \
+                    -(-self._tick_prefill_tokens // self.chunk_tokens) - 1
+            decoding = [i for i, r in enumerate(self._slots)
+                        if r is not None and self._active[i]]
+            if not decoding:
+                return chunk_ran
+            with TraceAnnotation("engine.decode", active=len(decoding)):
+                toks = self._dispatch_decode(decoding)
+            self.stats.decode_steps += 1
+            self.stats.occupancy_sum += len(decoding) / self.n_slots
+            with TraceAnnotation("engine.readback"):
+                nxt = np.asarray(toks, np.int32)
+                pos = np.asarray(self._cache["pos"])  # ONE host sync a step
+            for slot in decoding:
+                r = self._slots[slot]
+                r.out_tokens.append(int(nxt[slot]))
+                self._next_tok[slot, 0] = nxt[slot]
+                self._seen[slot, int(nxt[slot])] = True  # rep-penalty seen
+                self.stats.tokens_out += 1
+                if self._fresh[slot]:
+                    if r.t_first_token is None:   # resumed keep the original
+                        r.t_first_token = time.perf_counter()
+                        r.first_token_tick = self._tick
+                    self._fresh[slot] = False
+                # retire when out of budget OR out of cache: `pos` is the
+                # next write index, so the slot can take another decode
+                # step iff pos < max_len (the seed's `max_len - 1` retired
+                # one writable row early, and one row earlier still on the
+                # replay path)
+                if len(r.out_tokens) >= r.max_new_tokens \
+                        or int(pos[slot]) >= self.max_len:
+                    r.done = True
+                    r.t_done = time.perf_counter()
+                    self.stats.record_request(r)
+                    self._release(slot)
+            if self._window:
+                self._recycle_window_pages(pos)
+            return True
+
+    def _dispatch_decode(self, decoding: List[int]):
+        """Dispatch one batched decode step over the live slots; returns the
+        (n_slots,) next tokens, still on the device."""
+        tokens = {"tokens": jnp.asarray(self._next_tok)}
+        active = jnp.asarray(self._active)
         if any(self._temp[i] > 0 or self._rep_pen[i] != 1.0
                or self._bias_on[i] for i in decoding):
             counter = np.asarray(
@@ -1466,40 +1601,11 @@ class ServeEngine:
                       "seen": jnp.asarray(self._seen),
                       "bias": jnp.asarray(self._bias)}
             toks, self._cache = self._decode_sample_jit(
-                self.params, {"tokens": jnp.asarray(self._next_tok)},
-                self._cache, jnp.asarray(self._active), sample)
+                self.params, tokens, self._cache, active, sample)
         else:
             toks, self._cache = self._decode_jit(
-                self.params, {"tokens": jnp.asarray(self._next_tok)},
-                self._cache, jnp.asarray(self._active))
-        self.stats.decode_steps += 1
-        self.stats.occupancy_sum += len(decoding) / self.n_slots
-        nxt = np.asarray(toks, np.int32)
-        pos = np.asarray(self._cache["pos"])   # ONE host sync per step
-        for slot in decoding:
-            r = self._slots[slot]
-            r.out_tokens.append(int(nxt[slot]))
-            self._next_tok[slot, 0] = nxt[slot]
-            self._seen[slot, int(nxt[slot])] = True   # rep-penalty tracking
-            self.stats.tokens_out += 1
-            if self._fresh[slot]:
-                if r.t_first_token is None:   # resumed slots keep the original
-                    r.t_first_token = time.time()
-                    r.first_token_tick = self._tick
-                self._fresh[slot] = False
-            # retire when out of budget OR out of cache: `pos` is the next
-            # write index, so the slot can take another decode step iff
-            # pos < max_len (the seed's `max_len - 1` retired one writable
-            # row early, and one row earlier still on the replay path)
-            if len(r.out_tokens) >= r.max_new_tokens \
-                    or int(pos[slot]) >= self.max_len:
-                r.done = True
-                r.t_done = time.time()
-                self.stats.record_request(r)
-                self._release(slot)
-        if self._window:
-            self._recycle_window_pages(pos)
-        return True
+                self.params, tokens, self._cache, active)
+        return toks
 
     def _recycle_window_pages(self, pos):
         """Free pages that fell fully out of the attention window.
@@ -1579,13 +1685,13 @@ class ServeEngine:
             self._queue.remove(r)
             r.done = True
             r.timed_out = True
-            r.t_done = time.time()
+            r.t_done = time.perf_counter()
             self.stats.timeouts += 1
         for slot, r in enumerate(self._slots):
             if r is not None and expired(r):
                 r.done = True
                 r.timed_out = True
-                r.t_done = time.time()
+                r.t_done = time.perf_counter()
                 self.stats.timeouts += 1
                 self._release(slot)
 

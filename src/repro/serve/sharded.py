@@ -420,7 +420,7 @@ class ShardedServeEngine:
         req = Request(rid=self._next_rid, prompt=prompt,
                       max_new_tokens=max_new_tokens, extras=extras,
                       temperature=temperature, top_k=top_k, top_p=top_p,
-                      seed=int(seed), t_enqueue=time.time(),
+                      seed=int(seed), t_enqueue=time.perf_counter(),
                       submit_tick=self._tick, ttl_ticks=ttl_ticks)
         if ttl_ticks is not None:
             self._any_ttl = True
@@ -440,7 +440,7 @@ class ShardedServeEngine:
             if at is not None:
                 self._release(at[0] * self.slots_per_shard + at[1])
         req.done = True
-        req.t_done = time.time()
+        req.t_done = time.perf_counter()
 
     def _gslot(self, shard: int, slot: int) -> int:
         return shard * self.slots_per_shard + slot
@@ -667,13 +667,13 @@ class ShardedServeEngine:
             self.shard_tokens[g // self.slots_per_shard] += 1
             if self._fresh[g]:
                 if r.t_first_token is None:   # resumed slots keep the original
-                    r.t_first_token = time.time()
+                    r.t_first_token = time.perf_counter()
                     r.first_token_tick = self._tick
                 self._fresh[g] = False
             if len(r.out_tokens) >= r.max_new_tokens \
                     or int(self._pos[g]) >= self.max_len:
                 r.done = True
-                r.t_done = time.time()
+                r.t_done = time.perf_counter()
                 self.stats.record_request(r)
                 self._release(g)
         if self._window:
@@ -955,13 +955,13 @@ class ShardedServeEngine:
             q.remove(r)
             r.done = True
             r.timed_out = True
-            r.t_done = time.time()
+            r.t_done = time.perf_counter()
             self.stats.timeouts += 1
         for g, r in enumerate(self._slots):
             if r is not None and expired(r):
                 r.done = True
                 r.timed_out = True
-                r.t_done = time.time()
+                r.t_done = time.perf_counter()
                 self.stats.timeouts += 1
                 self._release(g)
 

@@ -105,3 +105,58 @@ def test_int8_matmul_compiles(one_chip, m, k, n):
     text = _compiled_text(int8_matmul, one_chip, ((m, k), jnp.bfloat16),
                           ((k, n), jnp.int8), ((n,), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def _kernels(lowered):
+    text = lowered.compile().as_text()
+    return sorted({k for line in text.splitlines() if "tpu_custom_call" in line
+                   for k in ("decode_attention", "flash_attention_paged")
+                   if k in line})
+
+
+def test_program_handle_lowers_the_loop_kernels(one_chip, monkeypatch):
+    """`ServeEngine.programs()` gives the decode and chunk programs with
+    the arguments the tick loop feeds them: lowered for the chip, each
+    holds the same kernels as the program lowered from the engine's own
+    arrays. Two layers of smollm-360m (with one, the chunk program's
+    attention feeds nothing and compiles away), bf16 weights and KV."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import ExecOptions, build_model
+    from repro.serve.engine import ServeEngine
+
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=2,
+                              vocab_size=1024)
+    model = build_model(cfg, ExecOptions(attn_impl="reference"))
+    eng = ServeEngine(model, params=model.init(jax.random.key(0)),
+                      n_slots=SLOTS, max_len=8 * PAGE, page_size=PAGE,
+                      chunk_pages=CHUNK // PAGE, wdtype="bf16",
+                      kv_dtype="bf16")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    z = jnp.zeros
+    loop = {
+        "decode": (eng._decode_jit, (
+            eng.params, {"tokens": z((SLOTS, 1), jnp.int32)}, eng._cache,
+            z((SLOTS,), bool))),
+        "chunk": (eng._chunk_jit, (
+            eng.params, {"tokens": z((1, CHUNK), jnp.int32),
+                         "start": z((1,), jnp.int32),
+                         "length": z((1,), jnp.int32),
+                         "page_row": z((eng.pages_per_seq,), jnp.int32)},
+            eng._cache))}
+    # the kernel dispatch asks the backend; the programs compile for the
+    # described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    progs = eng.programs()
+    assert set(progs) == set(loop)
+    want = {"decode": ["decode_attention"],
+            "chunk": ["flash_attention_paged"]}
+    for name, (fn, args) in progs.items():
+        got = _kernels(fn.lower(*on_chip(args)))
+        lfn, largs = loop[name]
+        assert got == _kernels(lfn.lower(*on_chip(largs))) == want[name]
