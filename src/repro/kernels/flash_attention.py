@@ -18,7 +18,9 @@ maps are shared via `paged_index_maps`). `kv_len` is the live length (rows
 the prompt has actually written), so stale pool rows and chunk padding are
 masked exactly like the decode kernel's ragged prefix. Optional
 k_scale/v_scale operands fuse int8 dequant into the tile loads, giving the
-int8 KV pool a chunked prefill path with no densify/cast step.
+int8 KV pool a chunked prefill path with no densify/cast step. With
+`layer=`, the pools are the layer-stacked lane-dense (L, n_pages, R, 128)
+pools, read in place one whole page a step, as in the decode kernel.
 
 NOT YET COVERED — MLA latent rows: `v_dim=` chunk attention (one latent
 pool as both K and V — see kernels/decode_attention's note) runs the exact
@@ -35,7 +37,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import NEG_INF, paged_index_maps
+from repro.kernels.decode_attention import (
+    NEG_INF, fold_lanes, layer_page_map, page_finish, page_tokens,
+    page_update, paged_index_maps, place_queries)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -214,10 +218,105 @@ def _paged_kernel(off_ref, kvlen_ref, pt_ref, *refs, scale: float,
             o_ref[0, h] = o.reshape(g, block_q, d).astype(o_ref.dtype)
 
 
+def _page_kernel(off_ref, kvlen_ref, layer_ref, pt_ref, q_ref, k_ref, v_ref,
+                 o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                 window: int, page_size: int, block_q: int, n_k: int):
+    """One (q-block × page) tile of chunk prefill over a lane-dense pool.
+
+    Head h's query rows are its T placed copies × G query heads × block_q
+    chunk rows, row r at chunk row r % block_q (see the decode kernel's
+    lane-dense mode). The layer and the page table are consumed by the
+    index_map."""
+    del layer_ref, pt_ref
+    nkv, tpr, g = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    rows = tpr * g * block_q
+    rh = k_ref.shape[2] // nkv
+    ib = pl.program_id(0)
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
+    kvlen = kvlen_ref[ib]
+    q_lo = off_ref[ib] + iq * block_q
+    k_lo = ik * page_size
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    live = jnp.logical_and(k_lo < kvlen, k_lo <= q_lo + block_q - 1)
+    if window > 0:
+        live = jnp.logical_and(live, k_lo + page_size > q_lo - window)
+
+    @pl.when(live)
+    def _page():
+        q_pos = q_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, rh), 0) % block_q
+        k_pos = k_lo + page_tokens(rows, rh, g * block_q, tpr)
+        ok = jnp.logical_and(k_pos <= q_pos, k_pos < kvlen)
+        if window > 0:
+            ok = jnp.logical_and(ok, q_pos - k_pos < window)
+        page_update(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
+                    scale=scale)
+
+    @pl.when(ik == n_k - 1)
+    def _done():
+        page_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref)
+
+
+def _paged_lane_dense(q, k_pool, v_pool, page_table, q_offset, kv_len, layer,
+                      *, window, scale, block_q, interpret):
+    """`flash_attention_paged` over the stacked lane-dense pools at
+    `layer`."""
+    b, cq, nkv, g, d = q.shape
+    lanes = k_pool.shape[-1]
+    assert lanes % d == 0, f"lane-dense kernel needs D | {lanes}, got {d}"
+    tpr = lanes // d
+    page_size = k_pool.shape[2] * lanes // (nkv * d)
+    pages_per_seq = page_table.shape[1]
+    # (B, KV, T, G, C, 128): head-major placed copies of the chunk's rows
+    qp = place_queries(jnp.transpose(q, (0, 2, 3, 1, 4)), axis=2,
+                       lanes=lanes)
+    q_spec = pl.BlockSpec((1, nkv, tpr, g, block_q, lanes),
+                          lambda ib, iq, ik, *_: (ib, 0, 0, 0, iq, 0))
+    lse_spec = pl.BlockSpec((1, nkv, tpr, g, block_q, 1),
+                            lambda ib, iq, ik, *_: (ib, 0, 0, 0, iq, 0))
+    page_spec = pl.BlockSpec((1, 1) + k_pool.shape[2:],
+                             layer_page_map(n_prefetch=4))
+    rows = tpr * g * block_q
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, cq // block_q, pages_per_seq),
+        in_specs=[q_spec, page_spec, page_spec],
+        out_specs=[q_spec, lse_spec],
+        scratch_shapes=[
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),       # m
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),       # l
+            pltpu.VMEM((nkv, rows, lanes), jnp.float32),   # acc
+        ],
+    )
+    o, lse = pl.pallas_call(
+        functools.partial(_page_kernel, scale=scale, window=window,
+                          page_size=page_size, block_q=block_q,
+                          n_k=pages_per_seq),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(qp.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(qp.shape[:-1] + (1,), jnp.float32)],
+        name="flash_attention_paged",
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(q_offset, kv_len, jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table, qp, k_pool, v_pool)
+    o = fold_lanes(o, lse, axis=2, d=d)                # (B, KV, G, C, D)
+    return jnp.transpose(o, (0, 3, 1, 2, 4)).astype(q.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "window", "scale", "block_q", "block_k", "interpret"))
 def flash_attention_paged(q, k_pool, v_pool, page_table, q_offset, kv_len, *,
-                          k_scale=None, v_scale=None, window: int = 0,
+                          layer=None, k_scale=None, v_scale=None,
+                          window: int = 0,
                           scale=None, block_q: int = 128,
                           block_k: "int | None" = None,
                           interpret: bool = False):
@@ -231,6 +330,9 @@ def flash_attention_paged(q, k_pool, v_pool, page_table, q_offset, kv_len, *,
                   logical page (null page 0 for unmapped entries).
       q_offset:   (B,) int32 — global position of the chunk's first row.
       kv_len:     (B,) int32 — live rows (this chunk's K/V already written).
+      layer:      () int — the pools are then the layer-stacked lane-dense
+                  (L, n_pages, R, 128) pools, read at this layer in place
+                  (float pools; no scales).
       k_scale/v_scale: optional (n_pages, page_size, KV) int8 dequant scales.
       window:     sliding-window size (0 = full causal).
 
@@ -240,17 +342,22 @@ def flash_attention_paged(q, k_pool, v_pool, page_table, q_offset, kv_len, *,
     assert (k_scale is None) == (v_scale is None)
     quantized = k_scale is not None
     scale = float(scale if scale is not None else d ** -0.5)
+    block_q = min(block_q, cq)
+    assert cq % block_q == 0, (cq, block_q)
+    q_offset = jnp.asarray(q_offset, jnp.int32).reshape(b)
+    kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
+    page_table = jnp.asarray(page_table, jnp.int32)
+    if layer is not None:
+        assert not quantized
+        return _paged_lane_dense(q, k_pool, v_pool, page_table, q_offset,
+                                 kv_len, layer, window=window, scale=scale,
+                                 block_q=block_q, interpret=interpret)
     page_size = k_pool.shape[1]
     pages_per_seq = page_table.shape[1]
     block_k = page_size if block_k is None else min(block_k, page_size)
     assert page_size % block_k == 0, (page_size, block_k)
     bpp = page_size // block_k
     n_k = pages_per_seq * bpp
-    block_q = min(block_q, cq)
-    assert cq % block_q == 0, (cq, block_q)
-    q_offset = jnp.asarray(q_offset, jnp.int32).reshape(b)
-    kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
-    page_table = jnp.asarray(page_table, jnp.int32)
     qf = jnp.transpose(q, (0, 2, 3, 1, 4))             # (B, KV, G, C, D)
 
     q_spec = pl.BlockSpec((1, nkv, g, block_q, d),

@@ -21,6 +21,61 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+LANES = 128
+
+
+# ---------------------------------------------------------------------------
+# Lane-dense page format of the paged float GQA pools
+# ---------------------------------------------------------------------------
+#
+# A page of a float GQA pool is stored as (R, 128) with R = page_size·KV·D /
+# 128: head-major, each head's page_size rows of D values laid end to end,
+# so head h, token t starts at flat offset (h·page_size + t)·D — row
+# h·(page_size·D/128) + t·D // 128, lane (t·D) % 128. At D = 64 a row holds
+# two tokens, at D = 128 one. The layout is what the Pallas kernels DMA a
+# page in, and the one the TPU compiler keeps as is: the minor (R, 128)
+# pair pads nothing, where a (KV, D) pair would.
+
+
+def lane_dense_ok(page_size: int, d: int) -> bool:
+    """Whether (page_size, D) fits the lane-dense page format: D divides
+    128 (whole tokens to a row) and a head's page fills whole rows. Pools
+    that do not fit (D = 256, say) keep the (page_size, KV, D) page."""
+    return LANES % d == 0 and (page_size * d) % LANES == 0
+
+
+def pack_pages(rows):
+    """(..., n, page_size, KV, D) token rows -> (..., n, R, 128) pages."""
+    *lead, n, ps, kv, d = rows.shape
+    assert lane_dense_ok(ps, d), (ps, d)
+    return jnp.swapaxes(rows, -3, -2).reshape(
+        *lead, n, kv * ps * d // LANES, LANES)
+
+
+def token_rows(t, kv: int, page_size: int, d: int):
+    """Where in-page token `t` ((B,) int) sits: its (B, KV) row for every
+    head and its (B,) lane slot — lanes [slot·D, (slot+1)·D) of the row."""
+    per_row = LANES // d
+    rows = (jnp.arange(kv)[None, :] * (page_size * d // LANES)
+            + (t // per_row)[:, None])
+    return rows, t % per_row
+
+
+def view_pages(pages, kv: int, d: int):
+    """(..., n, R, 128) pages -> (..., n, page_size, KV, D) token rows; the
+    inverse of `pack_pages`."""
+    *lead, n, r, lanes = pages.shape
+    ps = r * lanes // (kv * d)
+    return jnp.swapaxes(pages.reshape(*lead, n, kv, ps, d), -3, -2)
+
+
+def _gather_view(pool, page_table, layer, kv: int, d: int):
+    """(B, pages_per_seq·page_size, KV, D) dense view of each sequence's
+    pages: through the page table, and for a stacked lane-dense pool
+    (`layer` given) out of that layer's pages."""
+    pages = (pool[page_table] if layer is None
+             else view_pages(pool[layer, page_table], kv, d))
+    return pages.reshape(page_table.shape[0], -1, kv, d)
 
 
 def _mask_bias(q_pos, k_pos, *, causal: bool, window: int):
@@ -142,7 +197,7 @@ def _kernel_dispatch(k_cache) -> bool:
 
 
 def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
-                     page_table=None, k_scale=None, v_scale=None,
+                     page_table=None, layer=None, k_scale=None, v_scale=None,
                      v_dim: Optional[int] = None, impl: str = "auto"):
     """Single-position attention against a cache.
 
@@ -162,6 +217,10 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
     gathers the table back to a dense per-sequence view — exact, and the CPU
     oracle for the kernel — while the Pallas kernel gathers tile-by-tile
     through scalar prefetch and never materializes the dense view.
+
+    Lane-dense pools (`layer=` a scalar layer index, with `page_table`): the
+    caches are the layer-STACKED (L, n_pages, R, 128) pools in the page
+    format above, read in place at `layer` — no per-layer slice is taken.
 
     INT8 caches (`k_scale`/`v_scale`): the caches hold int8 rows and the
     scales hold one bf16 dequant factor per (position, kv head) — shaped like
@@ -190,16 +249,17 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
             decode_attention as pallas_decode)
         return pallas_decode(
             q, k_cache, v_cache, cur_len, window=window,
-            page_table=page_table, k_scale=k_scale, v_scale=v_scale,
+            page_table=page_table, layer=layer,
+            k_scale=k_scale, v_scale=v_scale,
             scale=None if scale is None else float(scale),
             interpret=jax.default_backend() != "tpu")
     b, _, nkv, g, d = q.shape
     if page_table is not None:
-        # (n_pages, ps, KV, D)[(B, pp)] → (B, pp·ps, KV, D) dense view.
+        # pages[(B, pp)] → (B, pp·ps, KV, D) dense view.
         # Null-page entries gather garbage rows, but they sit at logical
         # positions ≥ cur_len and are masked below like any dead row.
-        k_cache = k_cache[page_table].reshape(b, -1, nkv, d)
-        v_cache = v_cache[page_table].reshape(b, -1, nkv, d)
+        k_cache = _gather_view(k_cache, page_table, layer, nkv, d)
+        v_cache = _gather_view(v_cache, page_table, layer, nkv, d)
         if k_scale is not None:
             k_scale = k_scale[page_table].reshape(b, -1, nkv)
             v_scale = v_scale[page_table].reshape(b, -1, nkv)
@@ -233,8 +293,9 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=0, scale=None,
 
 
 def chunk_attention_paged(q, k_pool, v_pool, page_table, q_offset, *, kv_len,
-                          window=0, scale=None, k_scale=None, v_scale=None,
-                          v_dim: Optional[int] = None, impl: str = "auto"):
+                          window=0, scale=None, layer=None, k_scale=None,
+                          v_scale=None, v_dim: Optional[int] = None,
+                          impl: str = "auto"):
     """Chunk-prefill attention: a block of query rows against the page pool.
 
     q: (B, C, KV, G, D) — one fixed-size prefill chunk whose row i sits at
@@ -245,6 +306,9 @@ def chunk_attention_paged(q, k_pool, v_pool, page_table, q_offset, *, kv_len,
     rows, which the caller must already have written to the pool — and masks
     stale pool rows beyond it; causality masks by global position, so chunk
     padding rows only ever produce garbage outputs, never garbage inputs.
+
+    layer: with the layer-stacked lane-dense (L, n_pages, R, 128) pools,
+    the layer to read (see decode_attention).
 
     k_scale/v_scale: optional (n_pages, page_size, KV) scales for int8
     pools — the jnp path dequantizes the gathered view (CPU oracle), the
@@ -268,12 +332,12 @@ def chunk_attention_paged(q, k_pool, v_pool, page_table, q_offset, *, kv_len,
     if impl == "pallas":
         from repro.kernels.flash_attention import flash_attention_paged
         return flash_attention_paged(
-            q, k_pool, v_pool, page_table, q_offset, kv_len,
+            q, k_pool, v_pool, page_table, q_offset, kv_len, layer=layer,
             k_scale=k_scale, v_scale=v_scale, window=window,
             scale=float(scale), interpret=jax.default_backend() != "tpu")
     # reference: gather the table back to a dense logical view (CPU oracle)
-    kd = k_pool[page_table].reshape(b, -1, nkv, d)
-    vd = v_pool[page_table].reshape(b, -1, nkv, d)
+    kd = _gather_view(k_pool, page_table, layer, nkv, d)
+    vd = _gather_view(v_pool, page_table, layer, nkv, d)
     if k_scale is not None:
         from repro.models.quantized import dequantize_kv_rows
         kd = dequantize_kv_rows(kd, k_scale[page_table].reshape(b, -1, nkv))

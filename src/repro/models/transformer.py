@@ -16,7 +16,6 @@ Design notes (DESIGN.md §6):
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -201,6 +200,9 @@ def attn_block(x, p, cfg, opts: ExecOptions, *, positions,
                 through the slot's page row (`chunk=` dict with start (1,),
                 length (1,), page_row (pages_per_seq,)) and runs chunk
                 attention against the slot's live pages.
+    A lane-dense paged pool arrives layer-STACKED with the scan's layer
+    index as cache['layer'] ('decode' and 'chunk'): the writes and the
+    attention address that layer of it in place.
     `causal=False` (train/prefill only) serves the encdec encoder. int8
     storage is detected by the scale pools ('ks'/'vs') riding in `cache`;
     fp8 (e5m2) storage is a bare dtype cast, no scales.
@@ -232,13 +234,14 @@ def attn_block(x, p, cfg, opts: ExecOptions, *, positions,
     elif mode == "chunk":
         assert cache is not None and chunk is not None
         b, C = x.shape[:2]
-        pk, psk = _write_chunk(cache, "k", k[0], chunk)
-        pv, psv = _write_chunk(cache, "v", v[0], chunk)
+        with jax.named_scope("kv_pool"):
+            pk, psk = _write_chunk(cache, "k", k[0], chunk)
+            pv, psv = _write_chunk(cache, "v", v[0], chunk)
         qg = q.reshape(b, C, kvp, gp, cfg.head_dim)
         o = attn_mod.chunk_attention_paged(
             qg, pk, pv, chunk["page_row"][None], chunk["start"],
-            kv_len=chunk["start"] + chunk["length"],
-            window=cfg.window, scale=scale, k_scale=psk, v_scale=psv)
+            kv_len=chunk["start"] + chunk["length"], window=cfg.window,
+            scale=scale, layer=cache.get("layer"), k_scale=psk, v_scale=psv)
         o = o.reshape(b, C, cfg.n_heads_padded, cfg.head_dim)
         new_cache = _pool_entry(k=pk, v=pv, ks=psk, vs=psv)
     else:  # decode
@@ -247,13 +250,14 @@ def attn_block(x, p, cfg, opts: ExecOptions, *, positions,
         pos_b = positions.reshape(-1)             # (B,)
         page_table = cache.get("page_table")
         # write this step's k/v at each sequence position `pos_b`
-        k_cache, k_scale = _write_row(cache, "k", k, pos_b, page_table)
-        v_cache, v_scale = _write_row(cache, "v", v, pos_b, page_table)
+        with jax.named_scope("kv_pool"):
+            k_cache, k_scale = _write_row(cache, "k", k, pos_b, page_table)
+            v_cache, v_scale = _write_row(cache, "v", v, pos_b, page_table)
         qg = q.reshape(b, 1, kvp, gp, cfg.head_dim)
         o = attn_mod.decode_attention(
             qg, k_cache, v_cache, pos_b + 1,
             window=cfg.window, scale=scale, page_table=page_table,
-            k_scale=k_scale, v_scale=v_scale)
+            layer=cache.get("layer"), k_scale=k_scale, v_scale=v_scale)
         o = o.reshape(b, 1, cfg.n_heads_padded, cfg.head_dim)
         new_cache = _pool_entry(k=k_cache, v=v_cache, ks=k_scale, vs=v_scale)
 
@@ -350,11 +354,61 @@ def _write_chunk_paged_q(pool, spool, rows, start, length, page_row):
     return pool.at[page, r].set(q), spool.at[page, r].set(s)
 
 
+def _lane_dense_page_size(pool, kv: int, d: int) -> int:
+    """Tokens per page of a stacked lane-dense (L, n_pages, R, 128) pool."""
+    return pool.shape[2] * pool.shape[3] // (kv * d)
+
+
+def _write_row_lanes(pool, layer, kv_new, positions, page_table):
+    """Decode write into layer `layer` of a stacked lane-dense pool
+    (L, n_pages, R, 128), in place; kv_new: (B, 1, KV, D).
+
+    Each sequence's token lands at its page's row h·(ps·D/128) + t·D // 128
+    for every head h (models/attention's page format): the row is read,
+    this token's D lanes replaced and the whole 128-lane row written back.
+    Page selection and the null-page guard for retired slots are
+    `_write_cache_paged`'s."""
+    _, _, kv, d = kv_new.shape
+    lanes = pool.shape[3]
+    ps = _lane_dense_page_size(pool, kv, d)
+    logical = jnp.minimum(positions // ps, page_table.shape[1] - 1)
+    page = jnp.take_along_axis(page_table, logical[:, None], axis=1)[:, 0]
+    rows, slot = attn_mod.token_rows(positions % ps, kv, ps, d)
+    old = pool[layer, page[:, None], rows]                    # (B, KV, 128)
+    mine = (jnp.arange(lanes) // d)[None, None, :] == slot[:, None, None]
+    new = jnp.concatenate([kv_new[:, 0].astype(pool.dtype)] * (lanes // d),
+                          axis=-1)
+    return pool.at[layer, page[:, None], rows].set(
+        jnp.where(mine, new, old))
+
+
+def _write_chunk_lanes(pool, layer, rows, start, length, page_row):
+    """Chunk write into layer `layer` of a stacked lane-dense pool, in
+    place: rows (C, KV, D) at global positions start+i, as whole pages.
+
+    `start` is page-aligned (chunks start at 0 or after whole cached pages,
+    and advance by C, a whole number of pages), so the chunk covers C/ps
+    pages exactly. A page that holds no real row routes to the null page
+    (0), as `_chunk_pages` routes pad rows; a page's pad rows past `length`
+    sit at positions >= kv_len, masked until decode overwrites them."""
+    C, kv, d = rows.shape
+    ps = _lane_dense_page_size(pool, kv, d)
+    assert C % ps == 0, (C, ps)
+    n = C // ps
+    logical = jnp.minimum(start // ps + jnp.arange(n), page_row.shape[0] - 1)
+    page = jnp.where(jnp.arange(n) * ps < length, page_row[logical], 0)
+    pages = attn_mod.pack_pages(rows.reshape(n, ps, kv, d).astype(pool.dtype))
+    return pool.at[layer, page].set(pages)
+
+
 def _write_row(cache, key, kv_new, positions, page_table):
     """Write one decode row into `cache[key]` — dense or paged, any storage
     dtype. Returns (pool, scales-or-None). int8 storage is detected by the
     sibling scale pool `cache[key + 's']`; float storage (f32/bf16/fp8) is a
     bare cast on write."""
+    if "layer" in cache:
+        return _write_row_lanes(cache[key], cache["layer"], kv_new,
+                                positions, page_table), None
     if key + "s" in cache:
         if page_table is None:
             return _write_cache_q(cache[key], cache[key + "s"], kv_new,
@@ -371,6 +425,9 @@ def _write_chunk(cache, key, rows, chunk):
     `cache[key]` at global positions start+i. Returns (pool, scales-or-None);
     same int8 detection as `_write_row`."""
     start, length = chunk["start"][0], chunk["length"][0]
+    if "layer" in cache:
+        return _write_chunk_lanes(cache[key], cache["layer"], rows, start,
+                                  length, chunk["page_row"]), None
     if key + "s" in cache:
         return _write_chunk_paged_q(cache[key], cache[key + "s"], rows,
                                     start, length, chunk["page_row"])
@@ -407,7 +464,8 @@ def copy_pool_page(cache, src, dst):
     K/V with one more prefill chunk, the engine clones the cached bytes
     into a private page and maps THAT — the shared original stays
     read-only. Pages are schedule-independent bytes (`_round_kv`), so the
-    clone is exactly what a cold prefill would have produced."""
+    clone is exactly what a cold prefill would have produced. The page axis
+    is axis 1 in every page format, lane-dense pages included."""
     c = dict(cache)
     for key in _POOL_KEYS:
         if key in c:
@@ -417,7 +475,8 @@ def copy_pool_page(cache, src, dst):
 
 def gather_pool_pages(cache, page_ids):
     """Snapshot physical pages `page_ids` ((M,) int32) out of every pool in
-    the cache: {key: (L, M, page_size, ...)} — the migration outbox.
+    the cache: {key: (L, M, <page>)} — the migration outbox; a page is
+    (page_size, ...) or, lane-dense, (R, 128), always on axis 1.
 
     Mesh-free on purpose: `serve/sharded` wraps this in shard_map with the
     pool's page axis device-local, all_gathers the outboxes, and scatters
@@ -432,7 +491,7 @@ def gather_pool_pages(cache, page_ids):
 
 
 def set_pool_page(cache, dst, rows):
-    """Write one gathered page (`rows`: {key: (L, page_size, ...)}, e.g. an
+    """Write one gathered page (`rows`: {key: (L, <page>)}, e.g. an
     all_gathered `gather_pool_pages` outbox sliced to one move) into local
     physical page `dst` across every pool. `dst` may be a traced scalar;
     dst == 0 lands on the null page, which absorbs garbage by contract."""
@@ -443,10 +502,29 @@ def set_pool_page(cache, dst, rows):
     return c
 
 
-def _put_layer(pools, layer_cache, i):
-    """Write layer `i`'s updated pools back into the layer-stacked ones."""
+def _lane_dense(pools) -> bool:
+    """Whether the stacked pools are lane-dense (L, n_pages, R, 128) —
+    rank 4, where every other stacked pool with a data key is rank 5."""
+    return pools["k"].ndim == 4
+
+
+def _layer_cache(pools, i):
+    """Layer `i`'s view of the stacked pools inside a layer scan: the
+    lane-dense pools whole with the index (read and written in place), any
+    other pool sliced to the layer."""
+    if _lane_dense(pools):
+        return dict(pools, layer=i)
+    return {key: jax.lax.dynamic_index_in_dim(val, i, 0, keepdims=False)
+            for key, val in pools.items()}
+
+
+def _put_layer(pools, new_cache, i):
+    """The stacked pools after layer `i`: the lane-dense ones come back
+    whole from the writes; any other has layer `i`'s slice put back."""
+    if _lane_dense(pools):
+        return {key: new_cache[key] for key in pools}
     return {key: jax.lax.dynamic_update_index_in_dim(
-        val, layer_cache[key], i, 0) for key, val in pools.items()}
+        val, new_cache[key], i, 0) for key, val in pools.items()}
 
 
 def prefill_chunk(params, batch, cache, cfg, opts: ExecOptions):
@@ -495,14 +573,12 @@ def prefill_chunk(params, batch, cache, cfg, opts: ExecOptions):
         in_patch = (positions < batch["n_patch"][:, None])[..., None]
         x = jnp.where(in_patch, batch["patch_rows"].astype(x.dtype), x)
     chunk = {"start": start, "length": length, "page_row": batch["page_row"]}
-    dyn = functools.partial(jax.lax.dynamic_index_in_dim, axis=0,
-                            keepdims=False)
 
     def body(carry, xs):
         h, pools = carry
         lp, i = xs
         with jax.named_scope("kv_pool"):
-            layer_cache = {key: dyn(val, i) for key, val in pools.items()}
+            layer_cache = _layer_cache(pools, i)
         h, new_cache = layer_fn(h, lp, cfg, opts, positions=positions,
                                 mode="chunk", cache=layer_cache, chunk=chunk)
         with jax.named_scope("kv_pool"):
@@ -686,19 +762,21 @@ def decode_step(params, batch, cache, cfg, opts: ExecOptions):
     The layer-stacked KV cache rides the scan CARRY and is updated in place
     with dynamic-update-slice — streaming it through scan xs/ys instead
     double-buffers the whole cache as temps (measured +14 GiB/device on
-    gemma-7b × decode_32k; EXPERIMENTS.md §Perf P0c)."""
+    gemma-7b × decode_32k; EXPERIMENTS.md §Perf P0c). A lane-dense paged
+    pool is not even sliced: the write scatters into the carry at
+    [layer, page, row] and the kernel DMAs that layer's pages, so no step
+    copies the pool (a per-layer slice of a pool whose compact layout
+    differs from the kernel's costs six pool-sized copies a layer)."""
     tokens = batch["tokens"]
     positions = cache["pos"]                      # (B,) next position to write
     page_table = cache.get("page_table")          # read-only within the step
     x = embed_tokens(params, tokens, cfg, opts)
-    dyn = functools.partial(jax.lax.dynamic_index_in_dim, axis=0,
-                            keepdims=False)
 
     def body(carry, xs):
         h, pools = carry
         lp, i = xs
         with jax.named_scope("kv_pool"):
-            layer_cache = {key: dyn(val, i) for key, val in pools.items()}
+            layer_cache = _layer_cache(pools, i)
         if page_table is not None:
             layer_cache["page_table"] = page_table
         h, new_cache = layer_fn(h, lp, cfg, opts,
@@ -725,20 +803,31 @@ def decode_step(params, batch, cache, cfg, opts: ExecOptions):
 
 def paged_kv_shapes(L: int, batch: int, max_len: int, kv: int, hd: int,
                     dtype, page_size: int, n_pages: Optional[int],
-                    keys: Tuple[str, ...] = ("k", "v")):
+                    keys: Tuple[str, ...] = ("k", "v"),
+                    lane_dense: bool = False):
     """Shared paged-pool sizing contract (transformer + encdec cache_shape):
     (L, n_pages, page_size, KV, D) pools + a (B, max_len // page_size) page
     table. `keys` names the pools — ('k', 'v') for GQA, ('k',) for MLA's
     single latent pool. Physical page 0 is reserved by the serving engine as
     the null page, so `n_pages` defaults to one more than the dense worst
-    case (callers size it down to expected live tokens)."""
+    case (callers size it down to expected live tokens).
+
+    `lane_dense` (the decoder-only GQA families) stores each float pool
+    whose widths fit (`attn_mod.lane_dense_ok`) as
+    (L, n_pages, page_size·KV·D/128, 128) pages in the format of
+    models/attention's `pack_pages`, which the paged kernels read and the
+    layer scans write in place. int8 pools (with their scale pools) keep
+    the (page_size, KV, D) page."""
     assert max_len % page_size == 0, (max_len, page_size)
     pages_per_seq = max_len // page_size
     if n_pages is None:
         n_pages = 1 + batch * pages_per_seq
-    shapes = {
-        key: jax.ShapeDtypeStruct((L, n_pages, page_size, kv, hd), dtype)
-        for key in keys}
+    page = (page_size, kv, hd)
+    if lane_dense and dtype != jnp.int8 \
+            and attn_mod.lane_dense_ok(page_size, hd):
+        page = (page_size * kv * hd // attn_mod.LANES, attn_mod.LANES)
+    shapes = {key: jax.ShapeDtypeStruct((L, n_pages) + page, dtype)
+              for key in keys}
     shapes["page_table"] = jax.ShapeDtypeStruct((batch, pages_per_seq),
                                                 jnp.int32)
     shapes["pos"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
@@ -758,7 +847,8 @@ def cache_shape(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, *,
     ONE latent pool of (kv_lora_rank + qk_rope_dim)-wide rows, KV-head dim 1
     — the per-token bytes the latent family exists to shrink.
     Dense (default): per-slot (L, B, max_len, KV, D) rows.
-    Paged (`page_size=`): shared page pools — see `paged_kv_shapes`.
+    Paged (`page_size=`): shared page pools — see `paged_kv_shapes`; GQA
+    float pools take the lane-dense page format where their widths fit.
     dtype=jnp.int8 (either layout): rows stored int8 plus per-row bf16 dequant
     scale tensors ('ks'/'vs') — the serving engine's kv_dtype='int8' layout.
     dtype=jnp.float8_e5m2: bare fp8 rows, no scale tensors (dense layout;
@@ -779,4 +869,4 @@ def cache_shape(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, *,
                     (L, batch, max_len, kv), SCALE_DTYPE)
         return shapes
     return paged_kv_shapes(L, batch, max_len, kv, hd, dtype, page_size,
-                           n_pages, keys)
+                           n_pages, keys, lane_dense=cfg.attn_kind != "mla")

@@ -41,7 +41,9 @@ window mask already makes unreadable).
 
 Cache layout (PR 2 — paged KV):
   * Attention families default to a PAGED KV cache: one shared page pool of
-    (n_layers, n_pages, page_size, KV, D) K/V blocks plus a per-slot
+    (n_layers, n_pages, page_size, KV, D) K/V blocks (float GQA pools:
+    lane-dense (n_layers, n_pages, page_size·KV·D/128, 128) pages,
+    models/attention.pack_pages) plus a per-slot
     (n_slots, max_len // page_size) page table. Physical page 0 is the NULL
     page — never allocated, it absorbs writes from retired slots and backs
     unmapped table entries so every gather/DMA has a valid source. Admission
@@ -137,6 +139,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.analysis.sanitizer import register_entry_point
+from repro.models.attention import pack_pages
 from repro.models.quantized import quantize_kv_rows
 from repro.models.transformer import copy_pool_page, pool_data_keys
 from repro.serve.faults import FaultPlan
@@ -507,7 +510,7 @@ def _make_paste(fam: str):
     return paste
 
 
-def _make_paste_paged(fam: str):
+def _make_paste_paged(fam: str, ps: int):
     """Paged paste: scatter the dense prefill rows page-by-page into the
     shared pool and stamp the slot's page-table row.
 
@@ -515,18 +518,27 @@ def _make_paste_paged(fam: str):
     physical pages first, null page (0) for the rest. Prefill-bucket pad rows
     that spill past the reservation land on the null page; pad rows inside it
     sit at logical positions ≥ kv_len, masked until decode overwrites them —
-    the same invariant the dense replay path relies on."""
+    the same invariant the dense replay path relies on. A lane-dense pool
+    (rank 4, models/attention's page format) takes whole packed pages, the
+    last one zero-padded past the prompt."""
     assert fam in _ATTN_FAMILIES, fam
 
     def paste(cache, pf, slot, pos, page_row):
         c = dict(cache)
-        ps = c["k"].shape[2]
         blen = pf["k"].shape[2]
         n_prompt_pages = -(-blen // ps)    # static per prefill bucket
         int8_kv = "ks" in c
         # ('k', 'v') for GQA, ('k',) for MLA's single latent pool
         for key in pool_data_keys(pf):
             pool = c[key]
+            if pool.ndim == 4:
+                src = pf[key][:, 0].astype(pool.dtype)   # (L, blen, KV, D)
+                src = jnp.pad(src, ((0, 0), (0, n_prompt_pages * ps - blen),
+                                    (0, 0), (0, 0)))
+                c[key] = pool.at[:, page_row[:n_prompt_pages]].set(
+                    pack_pages(src.reshape(src.shape[0], n_prompt_pages, ps,
+                                           *src.shape[2:])))
+                continue
             if int8_kv:
                 qrows, srows = quantize_kv_rows(pf[key][:, 0])  # (L,blen,KV,·)
                 spool = c[key + "s"]
@@ -794,7 +806,7 @@ class ServeEngine:
             return toks, new_cache
 
         if self.paged:
-            _paste = _make_paste_paged(self.cfg.family)
+            _paste = _make_paste_paged(self.cfg.family, self.page_size)
 
             def _unmap(cache, slot):
                 # retired slot: point its whole table row at the null page so

@@ -9,13 +9,16 @@ step while every byte of KV traffic stays on the device that owns it.
 
 Layout invariants (what makes this GSPMD-proof instead of GSPMD-hostile):
   * **Contiguous page ranges per device.** The global K/V pools are
-    (L, n_shards · n_pages, page_size, KV, D), sharded on the page axis —
-    each device physically owns pages [shard·n_pages, (shard+1)·n_pages).
+    (L, n_shards · n_pages, <page>), sharded on the page axis (axis 1 in
+    either page format: (page_size, KV, D), or lane-dense (R, 128) for
+    float GQA pools) — each device physically owns pages
+    [shard·n_pages, (shard+1)·n_pages).
     Inside `shard_map` a device sees only its local (L, n_pages, ...) pool.
   * **Device-local page tables.** Table entries are LOCAL page ids
     (0..n_pages-1; local page 0 is each shard's null page). A slot's pages
     are reserved from its own shard's free list only, so the decode kernel's
-    scalar-prefetch gathers (kernels/decode_attention.paged_index_maps) and
+    scalar-prefetch gathers (kernels/decode_attention.paged_index_maps,
+    layer_page_map) and
     the chunk-prefill pool writes are local by construction — never a
     cross-device gather, which is exactly what the paged pool's scatter
     write pattern would otherwise force GSPMD to emit collectives for
