@@ -15,9 +15,12 @@ Readings:
   mean_gap   the sum of the gaps over the number of tokens compared
   share_over_<tol>  the share of served tokens whose gap exceeds tol
 
-The lower-precision control reads the same numbers: it is the program
-with its own lower-precision path switched on (int8 weights and int8 KV
-in place of bf16), and its served tokens are compared alike.
+The lower-precision control reads the same numbers, its served tokens
+compared alike with the reference at the configuration's own precision.
+For a bf16 configuration it is the program with its own lower-precision
+path switched on (int8 weights and int8 KV); for an int8 one, which has
+no lower path of its own, it is the program serving the same weights on
+the int4 grid (`weights.LEVELS`).
 """
 
 from __future__ import annotations

@@ -80,14 +80,42 @@ def host_idle_share(pdata) -> Optional[float]:
     return _total(idle_in_work) / (w1 - w0)
 
 
-def read(run) -> Optional[float]:
-    """`host_idle_share` of the run's traced window, in percent; None for
-    a run without a trace or a trace without the engine's spans."""
+def span_total(pdata, name: str, stat: str) -> Optional[float]:
+    """The sum of the metadata `stat` over the engine's `name` spans that
+    begin in the traced window; None where the trace holds no window or
+    no such span."""
+    windows = host_spans(pdata, (WINDOW,))
+    if len(windows) != 1:
+        return None
+    _, w0, w1 = windows[0]
+    found = None
+    for plane in pdata.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == name and w0 <= ev.start_ns < w1:
+                    found = (found or 0) + dict(ev.stats).get(stat, 0)
+    return found
+
+
+def cell_trace(run):
+    """The profiler trace `run.py` wrote for the run's cell, loaded; None
+    for an untraced run or a cell with no trace file."""
     if run.trace is None:
         return None
     try:
         path = find_xplane(str(TRACE_ROOT / run.cell["name"]))
     except FileNotFoundError:
         return None
-    share = host_idle_share(load(path))
+    return load(path)
+
+
+def read(run) -> Optional[float]:
+    """`host_idle_share` of the run's traced window, in percent; None for
+    a run without a trace or a trace without the engine's spans."""
+    pdata = cell_trace(run)
+    if pdata is None:
+        return None
+    share = host_idle_share(pdata)
     return None if share is None else 100.0 * share

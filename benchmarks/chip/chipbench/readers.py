@@ -38,6 +38,22 @@ def prefix_hit_share(run) -> Optional[float]:
     return None if total <= 0 else 100.0 * hit / total
 
 
+def output_tokens_per_s(run) -> Optional[float]:
+    """Tokens emitted in the window [t0, t1) by all requests, over the
+    window's seconds."""
+    w = run.window
+    n = sum(1 for r in run.records for t in r.stamps if w.t0 <= t < w.t1)
+    return n / w.seconds if n else None
+
+
+def batch_occupancy(run) -> Optional[float]:
+    """Mean share of the slots that decoded in each decode step of the
+    window."""
+    steps = run.window.delta("decode_steps")
+    return None if steps <= 0 else \
+        100.0 * run.window.delta("occupancy_sum") / steps
+
+
 def program_ms(run, program: str) -> Optional[float]:
     """Mean device time of one execution of a jitted program."""
     if run.trace is None or not run.trace.program_s.get(program):

@@ -10,9 +10,10 @@ bfloat16.
 
 Two things come from the deployment the configuration states, not from
 the program: the weights are the seeded ones the benchmark made, read as
-they are served (bf16, upcast exactly), and each K/V row rounds through
-the stated KV storage (bf16) before attention reads it, as a served cache
-holds it.
+they are served (bf16 upcast exactly; an int8 leaf `{"int8_q", "s"}` as
+int8_q x s in float32, which is exact), and each K/V row rounds through
+the stated KV storage before attention reads it, as a served cache holds
+it: bf16, or int8 with one bf16 scale per (token, KV head).
 
 Departure noted: rotary pairs are interleaved (dims 2i, 2i+1). That is
 ChatGLM3's published pairing; Llama's checkpoints pair dims i and i+d/2,
@@ -84,10 +85,24 @@ def rope(x, positions, dims: int, theta: float):
 
 
 def round_kv(x, kv_dtype: str):
-    """A K or V tensor (S, KV, D) as the stated KV storage holds it."""
+    """A K or V tensor (S, KV, D) as the stated KV storage holds it. int8:
+    symmetric over D, one scale per (token, KV head), absmax / 127 floored
+    at 1e-6 and rounded to bf16 before the ints are computed against it."""
     if kv_dtype == "bf16":
         return x.astype(jnp.bfloat16).astype(jnp.float32)
+    if kv_dtype == "int8":
+        s = jnp.maximum(jnp.max(jnp.abs(x), -1, keepdims=True) / 127.0,
+                        1e-6).astype(jnp.bfloat16).astype(jnp.float32)
+        return jnp.clip(jnp.round(x / s), -127, 127) * s
     raise ValueError(f"unknown KV storage {kv_dtype!r}")
+
+
+def dequant(w):
+    """A weight as served, in float32: an int8 leaf `{"int8_q", "s"}` is
+    int8_q x s (per-channel scale at the weight's rank), others upcast."""
+    if isinstance(w, dict):
+        return w["int8_q"].astype(jnp.float32) * w["s"]
+    return w.astype(jnp.float32)
 
 
 def attention(q, k, v, n_kv: int):
@@ -118,7 +133,7 @@ def layer(x, layers, i, *, shape: Shape, kv_dtype: str):
     """One decoder layer of the plain model over x (S, d_model)."""
     lw = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
         a, i, 0, keepdims=False), layers)
-    w = {k: v.astype(jnp.float32) for k, v in lw.items()}
+    w = {k: dequant(v) for k, v in lw.items()}
     pos = jnp.arange(x.shape[0])
     a = rms_norm(x, w["attn_norm"], shape.norm_eps)
     q = _mm("sd,dhk->shk", a, w["wq"])

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import glob
 import os
 import re
@@ -55,9 +56,17 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(path: str):
-    from jax.profiler import ProfileData
+    """The profile at `path`, a `.xplane.pb` or the newest under a
+    directory. The last file loaded is kept, so that the reduction and the
+    readers of one traced run share one load."""
     if os.path.isdir(path):
         path = find_xplane(path)
+    return _load_file(os.path.realpath(path), os.path.getmtime(path))
+
+
+@functools.lru_cache(maxsize=1)
+def _load_file(path: str, mtime: float):
+    from jax.profiler import ProfileData
     return ProfileData.from_file(path)
 
 

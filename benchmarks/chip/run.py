@@ -22,7 +22,13 @@ non-zero and prints no result.
 
 Options for measuring the benchmark itself (not used by its runs):
   --control       a whole run with the configuration's lower-precision
-                  control in the program's place; it must read false
+                  control in the program's place; it must read false.
+                  `check.control` in the configuration says which:
+                  kind "program" switches on the program's own path
+                  (`wdtype`, `kv`); kind "weights" serves the weights
+                  drawn on a coarser grid (`weights`, e.g. "int4" for an
+                  int8 deployment) while the reference reads the weights
+                  as the configuration states them
   --sweep r1,..   open-loop rates in turn, each on a fresh engine:
                   backlog and tails, to find the knee
 """
@@ -129,7 +135,8 @@ def check_published(cfg: Dict) -> None:
 def build_engine(model, params, serve: Dict, wdtype: Optional[str],
                  kv_dtype: str):
     """The program's engine as the configuration states it; `wdtype` int8
-    switches on its own int8 weight path (the control)."""
+    switches on its own int8 weight path (a control). Weights made in int8
+    come as the program's quantized leaves, with `wdtype` None."""
     from repro.serve.engine import ServeEngine
     return ServeEngine(model, params=params, n_slots=serve["n_slots"],
                        max_len=serve["max_len"],
@@ -212,6 +219,7 @@ class RunData:
     config: Dict
     mix: Dict
     chips: int
+    device: Dict
     setup_s: float
     window: serving.Window
     records: List[serving.Record]
@@ -244,15 +252,21 @@ def serve_once(cell: Dict, cfg: Dict, mix: Dict, model, seed: int,
                seconds: float, *, traced: bool, control: bool,
                events: Events, setup_clock: float):
     """Weights, engine, warm-up, window. Returns the engine's results and
-    the weights (for the reference)."""
+    the weights as the configuration states them (for the reference)."""
     prec = cfg["precision"]
-    if prec["weights"] != "bf16":
-        raise BenchError(f"weights are made in bf16, not {prec['weights']}")
-    params = weights.make_params(model, seed)
-    wd, kv = None, prec["kv"]
+    if prec["weights"] not in ("bf16", "int8"):
+        raise BenchError(f"weights are made in bf16 or int8, not "
+                         f"{prec['weights']}")
+    drawn, wd, kv = prec["weights"], None, prec["kv"]
     if control:
         ctl = cfg["check"]["control"]
-        wd, kv = ctl["wdtype"], ctl["kv"]
+        if ctl["kind"] == "program":
+            wd, kv = ctl["wdtype"], ctl["kv"]
+        elif ctl["kind"] == "weights":
+            drawn = ctl["weights"]
+        else:
+            raise BenchError(f"unknown control kind {ctl['kind']!r}")
+    params = weights.make_params(model, seed, drawn)
     in_use = (jax.devices()[0].memory_stats() or {}).get("bytes_in_use")
     engine = build_engine(model, params, cfg["serve"], wd, kv)
     st = jax.devices()[0].memory_stats() or {}
@@ -284,6 +298,10 @@ def serve_once(cell: Dict, cfg: Dict, mix: Dict, model, seed: int,
     peak = memory_peak()
     del engine
     gc.collect()
+    if drawn != prec["weights"]:
+        del params
+        gc.collect()
+        params = weights.make_params(model, seed, prec["weights"])
     return dict(params=params, records=records, window=window,
                 lateness=late, stalls=stalls, events=served_events,
                 setup_events=ev0,
@@ -333,7 +351,7 @@ def one_run(args, bench: Bench, cell, cfg, mix, model, dev,
     if out["trace_dir"] is not None:
         red = trace.reduce(trace.load(str(out["trace_dir"])), KERNELS)
     run = RunData(cell=cell, config=cfg, mix=mix, chips=cell["chips"],
-                  setup_s=out["setup_s"], window=out["window"],
+                  device=dev, setup_s=out["setup_s"], window=out["window"],
                   records=out["records"], measured=measured, trace=red)
     report_lines(run, out)
     t_ref = time.perf_counter()

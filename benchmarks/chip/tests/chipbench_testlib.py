@@ -50,7 +50,7 @@ def tiny_tree(tmp: pathlib.Path, *, config: str = "tiny-dense.json",
         "file": "benchmarks/chip/configs/tiny.json", "reduced": [],
         "why": "CPU tests"})
     for cell, like in (("tiny-chat", "smollm-chat"),
-                       ("tiny-batch", None),
+                       ("tiny-batch", "chatglm3-int8-batch"),
                        ("tiny-sessions", None)):
         spec["workloads"].append({
             "name": cell, "config": "tiny", "traffic": cell, "chips": 1,

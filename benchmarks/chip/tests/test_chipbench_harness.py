@@ -63,7 +63,7 @@ def test_closed_loop_opens_on_a_full_batch(tmp_path, capsys):
     root = tiny_tree(tmp_path)
     code, res, out = run_tiny(root, capsys, cell="tiny-batch", seconds=1.0)
     assert code == 0 and res is not None
-    assert set(res["metrics"]) == {"setup_s"}
+    assert set(res["metrics"]) == {"setup_s", "output_tokens_per_s"}
     assert res["failed"] == 0 and res["attempted"] >= 4
     assert res["correct"] is True
     counters = [ln for ln in out.splitlines()

@@ -4,6 +4,7 @@ hand-built trace whose answers are known, and on a small trace recorded
 on a v5e chip by the harness itself (tests/data/chip-trace.xplane.pb:
 0.12 s of smollm-chat)."""
 
+import os
 import types
 
 import pytest
@@ -86,3 +87,19 @@ def test_reduce_recorded_chip_trace():
     assert 0 < sum(s for _, s in red.idle_gaps) <= idle * (1 + 1e-9)
     assert {name for name, _ in red.idle_gaps} <= set(
         trace.HOST_SPANS) | {"outside_spans"}
+
+
+def test_load_reads_a_traced_run_once(tmp_path):
+    """The reduction and the trace readers of one run share one load: the
+    same file, named directly or by its directory, is read once; a file
+    written anew is read again."""
+    d = tmp_path / "cell" / "plugins" / "profile" / "1"
+    d.mkdir(parents=True)
+    f = d / "host.xplane.pb"
+    f.write_bytes((DATA / "chip-trace.xplane.pb").read_bytes())
+    first = trace.load(str(f))
+    assert trace.load(str(tmp_path / "cell")) is first
+    assert trace.reduce(first, KERNELS).n_devices == 1
+    f.write_bytes((DATA / "chip-trace.xplane.pb").read_bytes())
+    os.utime(f, (1, 1))
+    assert trace.load(str(f)) is not first
