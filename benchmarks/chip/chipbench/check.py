@@ -2,8 +2,9 @@
 
 Once the window has closed and the engine is freed, a sample of the
 requests it finished, drawn from the seed, goes through the plain
-reference (`reference.logits`), each prompt with the tokens that were
-served after it. For every served token the gap is the reference's best
+reference that the configuration names (`logits` of
+references/<name>.py), each prompt with the tokens that were served after
+it. For every served token the gap is the reference's best
 logit at that position minus its logit of the served token: 0 where they
 agree, and larger the worse the served token is by the reference's own
 judgement. Greedy decoding makes that the test: a served token may lose
@@ -25,12 +26,10 @@ the int4 grid (`weights.LEVELS`).
 
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Sequence
 
 import numpy as np
-
-from chipbench import reference
-from chipbench.reference import Shape
 
 # logit tolerances of the `share_over_<tol>` readings: the share of served
 # tokens that the reference ranks more than `tol` below its best
@@ -61,17 +60,19 @@ def sample(records: Sequence, rng: np.random.Generator,
     return picked
 
 
-def gaps(params, shape: Shape, kv_dtype: str,
+def gaps(ref: ModuleType, params, model: Dict, kv_dtype: str,
          picked: Sequence) -> np.ndarray:
-    """Per-token gaps of the served tokens of the picked requests."""
+    """Per-token gaps of the served tokens of the picked requests, by the
+    reference `ref` at the sizes `model` (the configuration's "model")."""
+    shape = ref.Shape.of(model)
     served = []
     for r in picked:
         prompt = np.asarray(r.req.prompt, np.int32)
         out = np.asarray(r.req.out_tokens, np.int32)
         seq = np.concatenate([prompt, out[:-1]])
         rows = np.arange(prompt.shape[0] - 1, seq.shape[0])
-        ref = reference.logits(params, shape, kv_dtype, seq, rows)
-        served.append(ref.max(axis=-1) - ref[np.arange(out.shape[0]), out])
+        lg = ref.logits(params, shape, kv_dtype, seq, rows)
+        served.append(lg.max(axis=-1) - lg[np.arange(out.shape[0]), out])
     return np.concatenate(served) if served else np.zeros(0)
 
 

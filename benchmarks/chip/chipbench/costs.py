@@ -8,7 +8,10 @@ writes (m, N) in bf16. Its least time on a chip is the larger of
 HBM peak: the int8 weights, the scales, the activations in and out.
 
 A decode step dispatches every projection of every layer once with m the
-engine's slot count; a prefill chunk with m its padded token count.
+engine's slot count; a prefill chunk with m its padded token count. Which
+projections a layer has, and how many parameters a token multiplies, are
+the architecture's: `projections` and `matmul_params` of the reference
+that the run's configuration names (`run.ref`).
 
 Peaks come from `peaks.json`, by the device kind JAX reports; a device
 that is not there gives no share.
@@ -16,10 +19,10 @@ that is not there gives no share.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from types import ModuleType
+from typing import Dict, Optional
 
 from chipbench import engine_trace
-from chipbench.reference import Shape
 from chipbench.spec import BENCH_DIR, load_json
 
 PEAKS = BENCH_DIR / "peaks.json"
@@ -29,15 +32,6 @@ SCALE_BYTES = 4             # float32 scale per output channel
 
 def peaks(kind: str) -> Optional[Dict[str, float]]:
     return load_json(PEAKS)["devices"].get(kind)
-
-
-def projections(shape: Shape) -> List[Tuple[int, int]]:
-    """(K, N) of each projection of one layer: q, k, v, o, and the
-    SwiGLU's gate, up and down."""
-    d, hd, ff = shape.d_model, shape.head_dim, shape.d_ff
-    return [(d, shape.n_heads * hd), (d, shape.n_kv_heads * hd),
-            (d, shape.n_kv_heads * hd), (shape.n_heads * hd, d),
-            (d, ff), (d, ff), (ff, d)]
 
 
 def int8_matmul_ops(m: int, k: int, n: int) -> int:
@@ -53,17 +47,11 @@ def int8_matmul_least_s(m: int, k: int, n: int, peak: Dict) -> float:
                int8_matmul_bytes(m, k, n) / peak["hbm_bytes_per_s"])
 
 
-def step_int8_matmul_least_s(shape: Shape, m: int, peak: Dict) -> float:
+def step_int8_matmul_least_s(ref: ModuleType, shape, m: int,
+                             peak: Dict) -> float:
     """Least int8_matmul time of one step (every layer's projections)."""
     return shape.n_layers * sum(int8_matmul_least_s(m, k, n, peak)
-                                for k, n in projections(shape))
-
-
-def matmul_params(shape: Shape) -> int:
-    """Parameters that a token multiplies: every layer's projections and
-    the output layer (the embedding is a gather)."""
-    return shape.n_layers * sum(k * n for k, n in projections(shape)) \
-        + shape.vocab * shape.d_model
+                                for k, n in ref.projections(shape))
 
 
 def int8_matmul_roofline(run) -> Optional[float]:
@@ -75,11 +63,11 @@ def int8_matmul_roofline(run) -> Optional[float]:
     kernel_s = run.trace.kernel_s.get("int8_matmul")
     if not kernel_s:
         return None
-    shape, serve = Shape.of(run.config["model"]), run.config["serve"]
+    shape, serve = run.ref.Shape.of(run.config["model"]), run.config["serve"]
     runs = {"_decode": serve["n_slots"],
             "_chunk": serve["chunk_pages"] * serve["page_size"]}
     least = sum(len(run.trace.program_s.get(prog, ()))
-                * step_int8_matmul_least_s(shape, m, peak)
+                * step_int8_matmul_least_s(run.ref, shape, m, peak)
                 for prog, m in runs.items())
     return 100.0 * least / kernel_s
 
@@ -99,5 +87,6 @@ def step_mfu(run) -> Optional[float]:
     tokens = (decoded or 0) + (chunked or 0)
     if not tokens:
         return None
-    ops = 2 * matmul_params(Shape.of(run.config["model"])) * tokens
+    shape = run.ref.Shape.of(run.config["model"])
+    ops = 2 * run.ref.matmul_params(shape) * tokens
     return 100.0 * ops / (run.trace.window_s * peak["int8_ops"])

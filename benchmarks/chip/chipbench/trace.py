@@ -9,7 +9,8 @@ program execution. Within the window:
 
   busy_s     the union of the operations' intervals, averaged over devices
   kernel_s   summed device time of the ops whose name or HLO metadata
-             names a kernel (`decode_attention`, ...), summed over devices
+             names a kernel (`decode_attention`, ...; the cell's kernels,
+             `run.kernel_names`), summed over devices
   program    device durations of each execution of a jitted program,
              keyed by the program's function name (`_decode`, `_chunk`)
   idle gaps  the intervals of device 0 with no operation, each labelled by
@@ -82,6 +83,15 @@ def _label(ev) -> str:
     return " ".join([ev.name] + [str(st[k]) for k in _LABEL_STATS if k in st])
 
 
+def kernels_in(label: str, kernels: Sequence[str]) -> List[str]:
+    """The kernels, in the order given, whose names `label` holds, less a
+    name it holds only inside a longer one: a kernel named after another
+    (`decode_attention_latent` beside `decode_attention`) is told apart
+    from it."""
+    hit = [k for k in kernels if k in label]
+    return [k for k in hit if not any(k != o and k in o for o in hit)]
+
+
 def _union(intervals: Sequence[Tuple[float, float]]) -> List[List[float]]:
     merged: List[List[float]] = []
     for a, b in sorted(intervals):
@@ -144,12 +154,10 @@ def reduce(pdata, kernels: Sequence[str], *, top: int = 10) -> Reduced:
             if c is None:
                 continue
             ivals.append(c)
-            label = _label(ev)
             op_ns[ev.name] += c[1] - c[0]
-            for k in kernels:
-                if k in label:
-                    kernel_ns[k] += c[1] - c[0]
-                    break
+            found = kernels_in(_label(ev), kernels)
+            if found:
+                kernel_ns[found[0]] += c[1] - c[0]
         merged = _union(ivals)
         busy_total += sum(b - a for a, b in merged)
         if MODULES_LINE in lines:

@@ -5,13 +5,15 @@
         --seconds <s> --trace <0|1>
 
 The cell (BENCHMARK.json `workloads`) names a configuration
-(configs/<name>.json), a traffic mix (traffic/<name>.json) and its chips.
-One process, which starts no other: set-up makes the weights on the device
-from the seed, builds the program's serving engine and warms the cell's
-own programs (from the compile cache at `bench-out/jax-cache` after the
-first run); then the traffic runs for `--seconds` in the measured window;
-then the engine is freed and a sample of what it served is compared with
-the plain float32 reference (chipbench/check.py).
+(configs/<name>.json), a traffic mix (traffic/<name>.json) and its chips;
+the configuration names its reference (references/<name>.py), which holds
+what is particular to its architecture (chipbench/spec.py). One process,
+which starts no other: set-up makes the weights on the device from the
+seed, builds the program's serving engine and warms the cell's own
+programs (from the compile cache at `bench-out/jax-cache` after the first
+run); then the traffic runs for `--seconds` in the measured window; then
+the engine is freed and a sample of what it served is compared with the
+plain float32 reference (chipbench/check.py).
 
 The last line of standard output is one JSON object: `correct`,
 `attempted`, `failed`, `metrics` (the cell's end-to-end metrics, or with
@@ -47,7 +49,8 @@ import json  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
-from typing import Dict, List, Optional, Sequence  # noqa: E402
+from types import ModuleType  # noqa: E402
+from typing import Dict, List, Optional, Sequence, Tuple  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -59,20 +62,15 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from chipbench import check, serving, trace, traffic, weights  # noqa: E402
-from chipbench.reference import Shape  # noqa: E402
 from chipbench.spec import Bench, load_json  # noqa: E402
 from chipbench.stats import percentile  # noqa: E402
 
 CACHE_DIR = ROOT / "bench-out" / "jax-cache"
 TRACE_DIR = ROOT / "bench-out" / "trace"
 TRACE_S = 4.0            # traced part of the window, from its start
+# kernels looked for and timed in every cell, beside those its
+# configuration names
 KERNELS = ("decode_attention", "flash_attention_paged", "int8_matmul")
-# the published numbers each program field must equal
-PROGRAM_FIELDS = {"n_layers": "n_layers", "d_model": "d_model",
-                  "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
-                  "head_dim": "head_dim", "d_ff": "d_ff",
-                  "vocab_size": "vocab", "tie_embeddings": "tie_embeddings",
-                  "qkv_bias": "qkv_bias", "rope_theta": "rope_theta"}
 
 
 class BenchError(RuntimeError):
@@ -104,19 +102,25 @@ def device_facts(chips: int, allow_cpu: bool) -> Dict[str, object]:
             "count": len(devs)}
 
 
-def program_arch(cfg: Dict):
+def kernel_names(cfg: Dict) -> Tuple[str, ...]:
+    """`KERNELS` and every kernel the configuration's programs name."""
+    named = [k for need in cfg["kernels"].values() for k in need]
+    return tuple(dict.fromkeys(KERNELS + tuple(named)))
+
+
+def program_arch(cfg: Dict, ref: ModuleType):
     """The program's ArchConfig for this configuration, checked against
-    the published numbers it must serve."""
+    the published numbers it must serve: the fields its reference names
+    (`PROGRAM_FIELDS`, `derived_fields`)."""
     from repro.configs import get_config
     prog = cfg["program"]
     arch = dataclasses.replace(get_config(prog["arch"]),
                                **prog.get("overrides", {}))
     model = cfg["model"]
     wrong = {f: (getattr(arch, f), model[k]) for f, k in
-             PROGRAM_FIELDS.items() if getattr(arch, f) != model[k]}
-    rope = int(arch.head_dim * arch.rope_fraction)
-    if rope != model["rope_dims"]:
-        wrong["rope_dims"] = (rope, model["rope_dims"])
+             ref.PROGRAM_FIELDS.items() if getattr(arch, f) != model[k]}
+    wrong.update({k: (v, model[k]) for k, v in
+                  ref.derived_fields(arch).items() if v != model[k]})
     if wrong:
         raise BenchError(f"program config {prog['arch']!r} departs from "
                          f"the published one: {wrong}")
@@ -158,9 +162,16 @@ def warm(engine, rng: np.random.Generator, vocab: int) -> None:
     engine.run_to_completion()
 
 
-def engine_kernels(engine) -> Dict[str, List[str]]:
-    """Pallas kernels in the engine's compiled decode and chunk programs,
-    lowered at the shapes its loop feeds them."""
+def found_kernels(hlo: str, names: Sequence[str]) -> List[str]:
+    """The kernels of `names` that a compiled program's text calls."""
+    return sorted({k for line in hlo.splitlines()
+                   if "tpu_custom_call" in line
+                   for k in trace.kernels_in(line, names)})
+
+
+def engine_kernels(engine, names: Sequence[str]) -> Dict[str, List[str]]:
+    """Pallas kernels of `names` in the engine's compiled decode and chunk
+    programs, lowered at the shapes its loop feeds them."""
     n = engine.n_slots
     progs = {
         "decode": engine._decode_jit.lower(
@@ -173,13 +184,8 @@ def engine_kernels(engine) -> Dict[str, List[str]]:
              "length": jnp.zeros((1,), jnp.int32),
              "page_row": jnp.zeros((engine.pages_per_seq,), jnp.int32)},
             engine._cache)}
-    found = {}
-    for name, lowered in progs.items():
-        text = lowered.compile().as_text()
-        found[name] = sorted({k for line in text.splitlines()
-                              if "tpu_custom_call" in line
-                              for k in KERNELS if k in line})
-    return found
+    return {prog: found_kernels(lowered.compile().as_text(), names)
+            for prog, lowered in progs.items()}
 
 
 def memory_peak() -> Optional[int]:
@@ -217,6 +223,7 @@ class RunData:
     """Everything a metric reader may read (metrics/<name>.py)."""
     cell: Dict
     config: Dict
+    ref: ModuleType          # the configuration's reference
     mix: Dict
     chips: int
     device: Dict
@@ -248,8 +255,8 @@ def outcome(records, measured, closed: bool):
     return len(measured), failed
 
 
-def serve_once(cell: Dict, cfg: Dict, mix: Dict, model, seed: int,
-               seconds: float, *, traced: bool, control: bool,
+def serve_once(cell: Dict, cfg: Dict, ref: ModuleType, mix: Dict, model,
+               seed: int, seconds: float, *, traced: bool, control: bool,
                events: Events, setup_clock: float):
     """Weights, engine, warm-up, window. Returns the engine's results and
     the weights as the configuration states them (for the reference)."""
@@ -266,14 +273,14 @@ def serve_once(cell: Dict, cfg: Dict, mix: Dict, model, seed: int,
             drawn = ctl["weights"]
         else:
             raise BenchError(f"unknown control kind {ctl['kind']!r}")
-    params = weights.make_params(model, seed, drawn)
+    params = weights.make_params(model, ref.WEIGHTS, seed, drawn)
     in_use = (jax.devices()[0].memory_stats() or {}).get("bytes_in_use")
     engine = build_engine(model, params, cfg["serve"], wd, kv)
     st = jax.devices()[0].memory_stats() or {}
     log(f"bytes in use: weights {in_use}, with the engine's pool "
         f"{st.get('bytes_in_use')} (nominal pool {engine.kv_cache_bytes()})")
     warm(engine, traffic.seed_rng(seed, 99), cfg["model"]["vocab"])
-    found = engine_kernels(engine)
+    found = engine_kernels(engine, kernel_names(cfg))
     for prog, need in cfg["kernels"].items():
         need = list(need) + (["int8_matmul"] if wd == "int8" else [])
         missing = [k for k in need if k not in found[prog]]
@@ -301,7 +308,8 @@ def serve_once(cell: Dict, cfg: Dict, mix: Dict, model, seed: int,
     if drawn != prec["weights"]:
         del params
         gc.collect()
-        params = weights.make_params(model, seed, prec["weights"])
+        params = weights.make_params(model, ref.WEIGHTS, seed,
+                                     prec["weights"])
     return dict(params=params, records=records, window=window,
                 lateness=late, stalls=stalls, events=served_events,
                 setup_events=ev0,
@@ -340,24 +348,26 @@ def report_lines(run: RunData, out: Dict) -> None:
         f"{k} {w.delta(k)}" for k in serving.COUNTERS))
 
 
-def one_run(args, bench: Bench, cell, cfg, mix, model, dev,
+def one_run(args, bench: Bench, cell, cfg, ref, mix, model, dev,
             events: Events) -> int:
-    out = serve_once(cell, cfg, mix, model, args.seed, args.seconds,
+    out = serve_once(cell, cfg, ref, mix, model, args.seed, args.seconds,
                      traced=bool(args.trace), control=args.control,
                      events=events, setup_clock=T_START)
     closed = mix["loop"] == "closed"
     measured = measured_records(out["records"], out["window"], closed)
     red = None
     if out["trace_dir"] is not None:
-        red = trace.reduce(trace.load(str(out["trace_dir"])), KERNELS)
-    run = RunData(cell=cell, config=cfg, mix=mix, chips=cell["chips"],
+        red = trace.reduce(trace.load(str(out["trace_dir"])),
+                           kernel_names(cfg))
+    run = RunData(cell=cell, config=cfg, ref=ref, mix=mix,
+                  chips=cell["chips"],
                   device=dev, setup_s=out["setup_s"], window=out["window"],
                   records=out["records"], measured=measured, trace=red)
     report_lines(run, out)
     t_ref = time.perf_counter()
     picked = check.sample(measured, traffic.seed_rng(args.seed, 11),
                           cfg["check"]["sample_tokens"])
-    read = check.readings(check.gaps(out["params"], Shape.of(cfg["model"]),
+    read = check.readings(check.gaps(ref, out["params"], cfg["model"],
                                      cfg["precision"]["kv"], picked))
     log(f"reference check{' of the control' if args.control else ''}: "
         f"{len(picked)} requests, {time.perf_counter() - t_ref} s; "
@@ -388,13 +398,13 @@ def one_run(args, bench: Bench, cell, cfg, mix, model, dev,
     return 0
 
 
-def sweep(args, cell, cfg, mix, model, events: Events) -> int:
+def sweep(args, cell, cfg, ref, mix, model, events: Events) -> int:
     """Open-loop rates in turn: where does the backlog start to grow?"""
     for i, rate in enumerate(args.sweep):
         m = dict(mix, rate_per_s=rate)
-        out = serve_once(cell, cfg, m, model, args.seed + i, args.seconds,
-                         traced=False, control=False, events=events,
-                         setup_clock=time.perf_counter())
+        out = serve_once(cell, cfg, ref, m, model, args.seed + i,
+                         args.seconds, traced=False, control=False,
+                         events=events, setup_clock=time.perf_counter())
         w = out["window"]
         meas = measured_records(out["records"], w, False)
         ttft = [r.stamps[0] - r.item.due for r in meas if r.stamps]
@@ -441,6 +451,7 @@ def main(argv: Optional[Sequence[str]] = None, *, root=None,
         args.seconds = float(bench.spec["run_seconds"])
     cell = bench.cell(args.workload)
     cfg = bench.config(cell["config"])
+    ref = bench.reference(cfg["reference"])
     mix = bench.traffic(cell["traffic"])
     try:
         dev = device_facts(cell["chips"], allow_cpu)
@@ -452,11 +463,11 @@ def main(argv: Optional[Sequence[str]] = None, *, root=None,
         events = Events()
         check_published(cfg)
         from repro.models import ExecOptions, build_model
-        model = build_model(program_arch(cfg),
+        model = build_model(program_arch(cfg, ref),
                             ExecOptions(attn_impl="reference"))
         if args.sweep:
-            return sweep(args, cell, cfg, mix, model, events)
-        return one_run(args, bench, cell, cfg, mix, model, dev, events)
+            return sweep(args, cell, cfg, ref, mix, model, events)
+        return one_run(args, bench, cell, cfg, ref, mix, model, dev, events)
     except BenchError as e:
         print(f"run.py: {e}", file=sys.stderr)
         return 2

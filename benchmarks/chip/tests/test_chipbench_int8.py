@@ -13,8 +13,7 @@ import numpy as np
 import pytest
 
 from chipbench_testlib import DATA, REPO, load_run, run_tiny, tiny_tree
-from chipbench import costs, engine_trace, readers, reference, serving, weights
-from chipbench.reference import Shape
+from chipbench import costs, engine_trace, plain, readers, serving, weights
 from chipbench.spec import Bench, load_json
 from chipbench.traffic import Item
 from test_chipbench_harness import _break_decode
@@ -22,12 +21,13 @@ from test_chipbench_trace import ev, plane
 
 CELL = "chatglm3-int8-batch"
 V5E = "TPU v5 lite"
+DENSE = Bench(REPO).reference("dense")
 
 
 def tiny_int8_model():
     from repro.models import ExecOptions, build_model
     cfg = load_json(DATA / "tiny-int8.json")
-    return build_model(load_run().program_arch(cfg),
+    return build_model(load_run().program_arch(cfg, DENSE),
                        ExecOptions(attn_impl="reference"))
 
 
@@ -39,9 +39,9 @@ def test_int8_draw_is_the_programs_leaf():
     from repro.models.quantized import (
         _ATTN_AXES, _FFN_AXES, quantize_params, quantize_weight_channelwise)
     model = tiny_int8_model()
-    drawn = weights.make_params(model, 2**40 + 3, "int8")
+    drawn = weights.make_params(model, DENSE.WEIGHTS, 2**40 + 3, "int8")
     prog = jax.eval_shape(lambda p: quantize_params(p, model.cfg),
-                          weights.make_params(model, 1))
+                          weights.make_params(model, DENSE.WEIGHTS, 1))
     assert jax.tree.structure(drawn) == jax.tree.structure(prog)
     for a, b in zip(jax.tree.leaves(drawn), jax.tree.leaves(prog)):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
@@ -65,11 +65,11 @@ def test_int4_control_is_the_same_draw_on_a_coarser_grid():
     """The control's weights lie on 15 levels a channel, each within half
     an int4 step of the int8 weights the reference reads."""
     model = tiny_int8_model()
-    w8 = weights.make_params(model, 5, "int8")["layers"]["w1"]
-    w4 = weights.make_params(model, 5, "int4")["layers"]["w1"]
+    w8 = weights.make_params(model, DENSE.WEIGHTS, 5, "int8")["layers"]["w1"]
+    w4 = weights.make_params(model, DENSE.WEIGHTS, 5, "int4")["layers"]["w1"]
     assert int(jnp.max(jnp.abs(w4["int8_q"]))) == 7
     np.testing.assert_allclose(w4["s"], w8["s"] * 127 / 7, rtol=1e-6)
-    gap = jnp.abs(reference.dequant(w4) - reference.dequant(w8))
+    gap = jnp.abs(plain.dequant(w4) - plain.dequant(w8))
     assert bool(jnp.all(gap <= w4["s"] * 0.5 + w8["s"] * 0.5 + 1e-7))
     assert float(jnp.mean(gap)) > 3 * float(jnp.mean(w8["s"]))
 
@@ -81,9 +81,10 @@ def test_reference_dequantizes_what_the_engine_serves(name, eq, n_contract):
     """The program's own einsum over an identity reads each served int8
     weight back exactly as the reference dequantizes it."""
     from repro.models.quantized import qeinsum
-    leaf = weights.make_params(tiny_int8_model(), 9, "int8")["layers"][name]
+    leaf = weights.make_params(tiny_int8_model(), DENSE.WEIGHTS, 9,
+                               "int8")["layers"][name]
     leaf = jax.tree.map(lambda a: a[1], leaf)
-    w = reference.dequant(leaf)
+    w = plain.dequant(leaf)
     contract = w.shape[:n_contract]
     n = int(np.prod(contract))
     eye = jnp.eye(n, dtype=jnp.float32).reshape((1, n) + contract)
@@ -102,7 +103,7 @@ def test_int8_kv_rounding_matches_a_hand_computation():
                     jnp.float32)
     s = 0.00787353515625
     want = np.asarray([[[127 * s, -64 * s, 32 * s, 13 * s]], [[0.0] * 4]])
-    got = reference.round_kv(x, "int8")
+    got = plain.round_kv(x, "int8")
     np.testing.assert_array_equal(np.asarray(got), want.astype(np.float32))
     np.testing.assert_array_equal(
         np.asarray(dequantize_kv_rows(*quantize_kv_rows(x))), np.asarray(got))
@@ -184,14 +185,14 @@ def test_batch_occupancy_differences_the_window():
 
 
 def chatglm_shape():
-    return Shape.of(Bench(REPO).config("chatglm3-6b-int8")["model"])
+    return DENSE.Shape.of(Bench(REPO).config("chatglm3-6b-int8")["model"])
 
 
 def test_int8_matmul_costs_at_a_chatglm3_projection():
     """The gate projection at decode: x (32, 4096) bf16, w (4096, 13696)
     int8, 13,696 float32 scales, out (32, 13696) bf16."""
     shape = chatglm_shape()
-    assert (4096, 13696) in costs.projections(shape)
+    assert (4096, 13696) in DENSE.projections(shape)
     assert costs.int8_matmul_ops(32, 4096, 13696) == 3_590_324_224
     assert costs.int8_matmul_bytes(32, 4096, 13696) == \
         262_144 + 56_098_816 + 54_784 + 876_544
@@ -200,7 +201,7 @@ def test_int8_matmul_costs_at_a_chatglm3_projection():
         pytest.approx(57_292_288 / 819e9)            # bound by HBM
     # per layer 2 x 16,777,216 (q, o) + 2 x 1,048,576 (k, v) + 3 x
     # 56,098,816 (SwiGLU); plus the 65,024 x 4,096 output layer
-    assert costs.matmul_params(shape) == 28 * 203_948_032 + 266_338_304
+    assert DENSE.matmul_params(shape) == 28 * 203_948_032 + 266_338_304
 
 
 def _traced_run(kind=V5E):
@@ -213,7 +214,7 @@ def _traced_run(kind=V5E):
     red = types.SimpleNamespace(
         window_s=10000e-9, kernel_s={"int8_matmul": 2e-6},
         program_s={"_decode": [1e-6, 1e-6], "_chunk": [2e-6]})
-    return types.SimpleNamespace(config=cfg, device={"kind": kind},
+    return types.SimpleNamespace(config=cfg, ref=DENSE, device={"kind": kind},
                                  trace=red, cell={"name": "x"})
 
 
@@ -260,7 +261,7 @@ def test_throughput_cell_is_found_by_name():
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("chatglm3-6b-int8", "batch", 1)
     cfg = bench.config(cell["config"])
-    arch = load_run().program_arch(cfg)
+    arch = load_run().program_arch(cfg, bench.reference(cfg["reference"]))
     assert (arch.n_layers, arch.d_model, arch.n_kv_heads) == (28, 4096, 2)
     assert cfg["precision"]["weights"] == "int8"
     serve = cfg["serve"]
@@ -286,6 +287,6 @@ def test_tiny_int8_config_departs_only_in_size():
     """The tiny int8 test configuration keeps ChatGLM3's block: qkv bias
     and rotary over half of each head, int8 weights and KV."""
     cfg = load_json(DATA / "tiny-int8.json")
-    arch = load_run().program_arch(cfg)
+    arch = load_run().program_arch(cfg, DENSE)
     assert arch.qkv_bias and arch.rope_fraction == 0.5
-    assert dataclasses.asdict(Shape.of(cfg["model"]))["rope_dims"] == 8
+    assert dataclasses.asdict(DENSE.Shape.of(cfg["model"]))["rope_dims"] == 8
